@@ -121,7 +121,7 @@ def mr_epoch_tile_rows(tiles=(8, 16, 32, 64, 128), n=256, reps=3):
     return rows, best
 
 
-def mr_epoch_block_rows(blocks=(4, 8, 16, 32), tile=32, n=256, reps=3):
+def mr_epoch_block_rows(blocks=(8, 16, 32), tile=32, n=256, reps=3):
     """Sweep the multi-tile ``block_lanes`` sub-blocking of ``mr_epoch``
     at a fixed lane tile (DESIGN.md §13).
 
@@ -191,9 +191,10 @@ def mr_epoch_compact_tile_rows(tiles=(8, 16, 32, 64), n=64, reps=3):
     timing drives :func:`epoch_schedule_compact` end to end (host loop,
     gather/scatter and chunked kernel included), so the winner is the
     tile the compact path should use at this lane count.  On CPU these
-    are interpret-mode numbers (rank, not TPU wall time); on a real TPU
-    the ``interpret=None`` default lowers the kernel natively
-    (``interpret=False``) and the same sweep re-ranks the tiles.
+    are interpret-mode numbers (rank, not TPU wall time).  On a TPU the
+    ``interpret=None`` default compiles the kernel with Mosaic; the
+    tile ranking there is not measured yet (``chip_smoke.py`` runs the
+    sweep path compiled at the 8-lane default tile).
     """
     import numpy as np
 
@@ -236,6 +237,8 @@ def all_rows():
 
 
 def main() -> None:
+    from repro.core.util import enable_compile_cache
+    enable_compile_cache()
     tile_rows, best_tile = mr_epoch_tile_rows()
     block_rows, best_block = mr_epoch_block_rows()
     compact_rows, best_tile_compact = mr_epoch_compact_tile_rows()

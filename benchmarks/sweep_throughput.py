@@ -586,6 +586,8 @@ def all_rows():
 
 
 def main() -> None:
+    from repro.core.util import enable_compile_cache
+    enable_compile_cache()
     rows = all_rows()
     by_name = {r[0]: r for r in rows}
     mixed = by_name["sweep_throughput_mixedpol_b2048"][1]

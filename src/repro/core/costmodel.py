@@ -57,8 +57,8 @@ _DEFAULT_PATH = pathlib.Path.home() / ".cache" / "repro-iotsim" / \
 # the fixed ROUND_DISPATCHES multiplier), so v1 caches re-measure.
 SCHEMA_VERSION = 2
 
-# Conservative CPU-ish coefficients used when measurement is disabled or
-# fails (e.g. a sandboxed FS): chosen to reproduce the retired static
+# Conservative CPU-ish coefficients used when measurement is disabled:
+# chosen to reproduce the retired static
 # heuristic's behaviour on the benchmark grids within a few percent.
 _FALLBACK_DISPATCH_US = 1500.0
 _FALLBACK_EPOCH_LANE_US = 0.030
@@ -328,9 +328,11 @@ def save_cost_model(model: CostModel, path) -> None:
 def default_cost_model(path=None, *, allow_measure: bool = True) -> CostModel:
     """The process-wide cost model: cached in memory, then in the JSON
     file at ``path`` (default ``$REPRO_COSTMODEL_PATH`` or
-    ``~/.cache/repro-iotsim/costmodel.json``), then measured.  Never
-    raises — an unwritable cache or failed measurement falls back to the
-    conservative built-in coefficients."""
+    ``~/.cache/repro-iotsim/costmodel.json``), then measured.  An
+    unwritable cache is tolerated; a failed measurement raises, so a
+    device is never scheduled with another device's constants.  The
+    conservative built-in coefficients serve only ``allow_measure=False``
+    with no cached calibration."""
     key = device_key()
     if key in _CACHE:
         return _CACHE[key]
@@ -342,15 +344,11 @@ def default_cost_model(path=None, *, allow_measure: bool = True) -> CostModel:
         except (OSError, ValueError, KeyError):
             model = None
     if model is None and allow_measure:
+        model = measure()
         try:
-            model = measure()
-        except Exception:                      # pragma: no cover - env
-            model = None
-        if model is not None:
-            try:
-                save_cost_model(model, path)
-            except OSError:                    # pragma: no cover - env
-                pass
+            save_cost_model(model, path)
+        except OSError:                        # pragma: no cover - env
+            pass
     if model is None:
         model = fallback_cost_model(key)
     _CACHE[key] = model

@@ -895,8 +895,11 @@ class SweepPlan:
         ``backend`` selects the engine: ``"xla"`` (default) is
         :func:`engine.simulate_batch_arrays`; ``"pallas"`` runs the fused
         ``mr_epoch`` megakernel (``kernels/mr_sched``) with per-VM/task
-        state resident in VMEM across epochs (interpret mode off-TPU;
-        single-device only — combine with ``chunk``, not ``mesh``).
+        state resident in VMEM across epochs: compiled by Mosaic on a TPU
+        backend (``chip_smoke.py`` runs it on one v5e chip against the
+        XLA engine and the oracle), interpreted on any other backend,
+        which only the CPU tests use; single-device only — combine with
+        ``chunk``, not ``mesh``.
 
         ``stream_to`` (with ``chunk``) streams results to disk instead of
         accumulating them: each ``chunk``-cell slice of the grid is

@@ -7,10 +7,34 @@ compacted active-lane counts (``engine.simulate_batch_arrays_compact``,
 (``costmodel``).  Hoisted here because the measured-cost bucket scorer
 evaluates many candidate partitions per plan, which made the original
 per-unique-value Python loop a hot spot.
+
+``enable_compile_cache`` is the one place the entry points (``chip_smoke.py``
+and the benchmarks) turn on JAX's persistent compilation cache.
 """
 from __future__ import annotations
 
+import os
+import pathlib
+
 import numpy as np
+
+# the checkout root (src/repro/core/util.py -> three levels up)
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here.  Otherwise the cache lives at ``.jax_cache/`` in
+    the checkout: a fixed path, since the path is part of the cache key.
+    Called by entry points, never at import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+        path = str(_CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 # floor * 2**j ladder, precomputed far past any realistic padding; the
 # table form makes the vectorized rounding exact (no float log2 edge

@@ -132,14 +132,26 @@ def _kernel(*refs, T: int, V: int, max_pes: int, epoch_bound: int,
     spinup = spinup_ref[...]                     # (tile, 1) boot delay
     prio = prio_ref[...]                         # (tile, T) admission prio
 
-    vm_onehot = (task_vm[..., None]
-                 == jax.lax.broadcasted_iota(jnp.int32,
-                                             (1, 1, V), 2))  # (tile,T,V)
-    onehot_b = vm_onehot
-    vm_onehot = vm_onehot.astype(jnp.float32)
+    onehot_b = (task_vm[..., None]
+                == jax.lax.broadcasted_iota(jnp.int32,
+                                            (1, 1, V), 2))   # (tile,T,V)
     idx = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)     # (1, T)
     vidx = jax.lax.broadcasted_iota(jnp.int32, (1, V), 1)    # (1, V)
-    task_pes0 = jnp.einsum("stv,sv->st", vm_onehot, vm_pes)
+
+    # The one-hot contractions lower as masked select-and-reduce on the
+    # VPU, not as dot_generals (Mosaic has no batched (T,V) contraction,
+    # and an f32 MXU pass could round).  Both are exact, so the results
+    # are bitwise what a contraction gives: a gather sums one selected
+    # value with zeros, and every per-VM sum below adds 0/1 indicators.
+    def gather(oh_b, per_vm):
+        """Each task's value of a per-VM quantity (tile,V) -> (tile,T)."""
+        return jnp.sum(jnp.where(oh_b, per_vm[:, None, :], 0.0), axis=2)
+
+    def vm_sum(oh_b, per_task):
+        """Per-VM sum of a per-task 0/1 indicator (tile,T) -> (tile,V)."""
+        return jnp.sum(jnp.where(oh_b, per_task[..., None], 0.0), axis=1)
+
+    task_pes0 = gather(onehot_b, vm_pes)
 
     if control:
         vm_valid = vm_valid_ref[...] != 0        # (tile, V)
@@ -158,8 +170,6 @@ def _kernel(*refs, T: int, V: int, max_pes: int, epoch_bound: int,
         dl_slack = dl_slack_ref[...]             # (tile, 1) f32
         pre_onl = (preempt_ref[...] != 0) & is_space   # (tile, 1)
         res_onl = resume_ref[...] != 0           # (tile, 1)
-        onehot2_b = (task_vm2[..., None]
-                     == jax.lax.broadcasted_iota(jnp.int32, (1, 1, V), 2))
         # per-lane epoch bound (engine._lane_bound, additive): each
         # robustness mechanism's term is paid only by lanes whose encoded
         # data can trigger it — degenerate lanes keep the exact open-loop
@@ -176,41 +186,45 @@ def _kernel(*refs, T: int, V: int, max_pes: int, epoch_bound: int,
 
     # Lease admission windows (DESIGN.md §8), gathered per task with the
     # exact f32 ops the engine's _epoch_setup uses (one-hot gathers are
-    # exact; vm_stop carries the _BIG stand-in, never inf — 0 * inf would
-    # NaN these einsums).  Static fleets make every use below a bitwise
-    # identity with the pre-elastic kernel.  Under control these are
-    # re-derived every epoch from the carried realized windows instead.
-    avail_t0 = jnp.einsum("stv,sv->st", vm_onehot, vm_start + spinup)
-    close_t0 = jnp.einsum("stv,sv->st", vm_onehot, vm_stop)
+    # exact; vm_stop carries the _BIG stand-in, never inf).  Static
+    # fleets make every use below a bitwise identity with the
+    # pre-elastic kernel.  Under control these are re-derived every
+    # epoch from the carried realized windows instead.
+    avail_t0 = gather(onehot_b, vm_start + spinup)
+    close_t0 = gather(onehot_b, vm_stop)
 
     # carry state arrives as refs (the wrapper builds the canonical
     # initial state with the exact constants this kernel used to
     # initialize in VMEM — compacted/chunked drivers resume mid-history
-    # by feeding a previous call's state back in)
-    state = (
-        state_in[0][...][:, 0],                          # time
-        state_in[1][...],                                # rem
-        state_in[2][...] != 0,                           # running
-        state_in[3][...],                                # start
-        state_in[4][...],                                # finish
+    # by feeding a previous call's state back in).  The loop carry keeps
+    # the refs' layout — every leaf 2-D and 32-bit (masks as int32,
+    # per-lane scalars as (tile, 1)), which is what Mosaic's scf.while
+    # lowering accepts; ``unpack``/``pack`` convert at the loop edges.
+    state = tuple(r[...] for r in state_in[:5]) + (
         ready0_ref[...],                                 # ready
-        state_in[5][...][:, 0],                          # maps_left
-        state_in[6][...][:, 0],                          # lane epochs
-        jnp.int32(0),                                    # epochs this call
+        state_in[5][...],                                # maps_left
+        state_in[6][...],                                # lane epochs
+        jnp.zeros((1, 1), jnp.int32),                    # epochs this call
     )
     if control:
-        state = state + (
-            state_in[7][...] != 0,                       # hit
-            state_in[8][...],                            # vm_open
-            state_in[9][...],                            # vm_close
-            state_in[10][...][:, 0],                     # n_scale
-            state_in[11][...] != 0,                      # shed
-            state_in[12][...],                           # n_evict
-            state_in[13][...][:, 0],                     # work_lost
-        )
+        state = state + tuple(r[...] for r in state_in[7:14])
     if trace:
         vm_valid_t = vm_valid_ref[...] != 0              # (tile, V)
         state = state + (state_in[-1][...],)             # ts rows (tile,C*8)
+
+    # carry leaves held as (tile, 1) per-lane scalars / int32 masks
+    lane_leaves = (0, 6, 7) + ((12, 15) if control else ())
+    mask_leaves = (2,) + ((9, 13) if control else ())
+
+    def unpack(st):
+        return tuple(x[:, 0] if k in lane_leaves
+                     else x != 0 if k in mask_leaves else x
+                     for k, x in enumerate(st))
+
+    def pack(st):
+        return tuple(x[:, None] if k in lane_leaves
+                     else x.astype(jnp.int32) if k in mask_leaves else x
+                     for k, x in enumerate(st))
 
     def lanes_active(finish, lane_ep, shed=None):
         unfin = valid & (finish >= _BIG / 2)
@@ -224,10 +238,12 @@ def _kernel(*refs, T: int, V: int, max_pes: int, epoch_bound: int,
         return act
 
     def cond(st):
-        act = lanes_active(st[4], st[7], st[13] if control else None)
-        return jnp.any(act) & (st[8] < epoch_bound)
+        act = lanes_active(st[4], st[7][:, 0],
+                           st[13] != 0 if control else None)
+        return jnp.any(act) & (st[8][0, 0] < epoch_bound)
 
     def epoch(st):
+        st = unpack(st)
         (time, rem, running, start, finish, ready, maps_left, lane_ep,
          n) = st[:9]
         active = lanes_active(finish, lane_ep,
@@ -242,18 +258,19 @@ def _kernel(*refs, T: int, V: int, max_pes: int, epoch_bound: int,
         if control:
             (hit, vm_open, vm_close, n_scale, shed0, n_evict0,
              work_lost) = st[9:16]
-            cur_oh_b = jnp.where(hit[..., None], onehot2_b, onehot_b)
-            cur_oh = cur_oh_b.astype(jnp.float32)
+            # one-hot of each task's current slot (Mosaic cannot insert
+            # a minor dim into a bool vector, so select the VM ids first)
+            cur_oh_b = (jnp.where(hit, task_vm2, task_vm)[..., None]
+                        == jax.lax.broadcasted_iota(jnp.int32, (1, 1, V), 2))
         else:
-            cur_oh_b, cur_oh = onehot_b, vm_onehot
+            cur_oh_b = onehot_b
 
         def to_task(per_vm):
-            """Gather a per-VM quantity to each task's current VM
-            (exact: one-hot)."""
-            return jnp.einsum("stv,sv->st", cur_oh, per_vm)
+            """Gather a per-VM quantity to each task's current VM."""
+            return gather(cur_oh_b, per_vm)
 
         def per_vm_sum(per_task):
-            return jnp.einsum("stv,st->sv", cur_oh, per_task)
+            return vm_sum(cur_oh_b, per_task)
 
         if control:
             task_pes = to_task(vm_pes)
@@ -509,8 +526,7 @@ def _kernel(*refs, T: int, V: int, max_pes: int, epoch_bound: int,
             idx_m = jnp.where(cand, idx, T)
             min_idx_v = jnp.min(
                 jnp.where(cur_oh_b, idx_m[..., None], T), axis=1)
-            pick = cand & (idx == jnp.einsum(
-                "stv,sv->st", cur_oh,
+            pick = cand & (idx == to_task(
                 min_idx_v.astype(jnp.float32)).astype(jnp.int32))
             admit = admit | (pick & (jnp.float32(s) < free_after))
             remaining = remaining & ~pick
@@ -558,37 +574,24 @@ def _kernel(*refs, T: int, V: int, max_pes: int, epoch_bound: int,
                 b_f = (jnp.sum((open_v & busy_v).astype(jnp.float32),
                                axis=1) / jnp.maximum(n_o, 1.0))
                 n_fail = n_shed = n_ev = jnp.zeros_like(actf)
+            # flat (C * 8) row layout: column j is field j % 8 of row
+            # j // 8 (shifts, not a 3-D reshape, which Mosaic refuses)
             ts = st[-1]
-            C = ts.shape[1] // 8
-            row = (jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
-                   == lane_ep[:, None]).astype(jnp.float32) * actf[:, None]
-            vals = jnp.stack([time, q_d, b_f, n_o, actf,
-                              n_fail, n_shed, n_ev], axis=-1)
-            ts = (ts.reshape(ts.shape[0], C, 8)
-                  + row[:, :, None] * vals[:, None, :]
-                  ).reshape(ts.shape[0], C * 8)
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, ts.shape[1]), 1)
+            row = ((col >> 3) == lane_ep[:, None]).astype(jnp.float32) \
+                * actf[:, None]
+            vals = jnp.zeros_like(ts)
+            for k, v in enumerate((time, q_d, b_f, n_o, actf, n_fail,
+                                   n_shed, n_ev)):
+                vals = jnp.where((col & 7) == k, v[:, None], vals)
+            ts = ts + row * vals
             new = new + (ts,)
-        return new
+        return pack(new)
 
     st = jax.lax.while_loop(cond, epoch, state)
-    out_refs[0][...] = st[0][:, None]
-    out_refs[1][...] = st[1]
-    out_refs[2][...] = st[2].astype(jnp.int32)
-    out_refs[3][...] = st[3]
-    out_refs[4][...] = st[4]
-    out_refs[5][...] = st[5]
-    out_refs[6][...] = st[6][:, None]
-    out_refs[7][...] = st[7][:, None]
-    if control:
-        out_refs[8][...] = st[9].astype(jnp.int32)
-        out_refs[9][...] = st[10]
-        out_refs[10][...] = st[11]
-        out_refs[11][...] = st[12][:, None]
-        out_refs[12][...] = st[13].astype(jnp.int32)
-        out_refs[13][...] = st[14]
-        out_refs[14][...] = st[15][:, None]
-    if trace:
-        out_refs[-1][...] = st[-1]
+    # outputs mirror the carry layout minus the call-local epoch counter
+    for ref, x in zip(out_refs, st[:8] + st[9:]):
+        ref[...] = x
 
 
 def initial_state(task_len, ready0, is_red, valid, vm_start=None,
@@ -753,6 +756,13 @@ def _mr_epoch_impl(task_len, task_vm, ready0, is_red, valid, shuffle,
         block = max(1, min(int(block_lanes), tile))
         while tile % block:
             block //= 2
+    if not interpret and block % 8 and block != N:
+        # Mosaic tiles the second-minor (lane) dim of every block by 8
+        raise ValueError(
+            f"mr_epoch: a compiled kernel needs lane blocks that are a "
+            f"multiple of 8 or span all N={N} lanes; tile={tile}, "
+            f"block_lanes={block_lanes} give blocks of {block} (pad N to "
+            f"a multiple of 8)")
     nsub = tile // block
     if block_lanes is None:
         grid = (N // tile,)

@@ -71,11 +71,30 @@ def _control_derived(batch: ScenarioArrays):
     return task_vm2, refetch
 
 
+# Lanes per ``mr_epoch`` block when compiled by Mosaic, which unrolls the
+# epoch body over the block's vector registers: on v5e the T=32 open-loop
+# kernel compiles in ~2 s at 8 lanes and ~24 s at 64, and the compacted
+# driver compiles once per pow2 batch size.  8 is the f32 sublane tile,
+# the smallest block Mosaic accepts.  Interpret mode keeps 64-lane tiles.
+COMPILED_TILE = 8
+INTERPRET_TILE = 64
+
+
+def resolve_mode(interpret: bool | None, tile: int | None):
+    """``(interpret, tile)`` for a kernel call: ``interpret=None``
+    compiles with Mosaic on a TPU backend and interprets elsewhere (the
+    CPU test path); ``tile=None`` takes the mode's default tile."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if tile is None:
+        tile = INTERPRET_TILE if interpret else COMPILED_TILE
+    return interpret, tile
+
+
 def schedule(batch: ScenarioArrays, *, tile: int = 64,
              interpret: bool | None = None):
     """batch: stacked single-job scenarios (leading dim N)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret, tile = resolve_mode(interpret, tile)
     task_len, ready0, shuffle = _derived_inputs(batch)
     return mr_schedule(
         task_len.astype(jnp.float32), batch.task_vm.astype(jnp.int32),
@@ -115,7 +134,7 @@ def _control_lane_data(batch: ScenarioArrays, pad, task_vm2, refetch):
             pad(batch.preempt_resume.astype(jnp.int32)[:, None]))
 
 
-def epoch_schedule(batch: ScenarioArrays, *, tile: int = 64,
+def epoch_schedule(batch: ScenarioArrays, *, tile: int | None = None,
                    max_pes: int | None = None,
                    interpret: bool | None = None,
                    control: bool = False, trace: bool = False,
@@ -142,9 +161,10 @@ def epoch_schedule(batch: ScenarioArrays, *, tile: int = 64,
     ``block_lanes`` re-tiles each macro tile across a minor grid
     dimension (double-buffered HBM→VMEM streaming on real TPUs, bitwise
     in interpret mode — see ``mr_epoch``).
+
+    ``interpret``/``tile`` default per :func:`resolve_mode`.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret, tile = resolve_mode(interpret, tile)
     if max_pes is None:
         if isinstance(batch.vm_pes, jax.core.Tracer):
             max_pes = 8
@@ -246,7 +266,8 @@ def _state_activity(valid, finish, shed):
 
 
 def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
-                           tile: int = 64, max_pes: int | None = None,
+                           tile: int | None = None,
+                           max_pes: int | None = None,
                            interpret: bool | None = None, floor: int = 8,
                            cost_model=None, control: bool = False,
                            trace: bool = False, stats: dict | None = None,
@@ -305,8 +326,7 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
     stats.setdefault("compactions", 0)
     stats.setdefault("dispatches", 0)
     validate_pow2_floor(floor)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret, tile = resolve_mode(interpret, tile)
     if max_pes is None:
         max_pes = max(int(np.ceil(float(jnp.max(batch.vm_pes)))), 1)
     N, T = batch.task_vm.shape

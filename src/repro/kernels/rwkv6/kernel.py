@@ -41,16 +41,16 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_ref, *, bt: int):
 
     def step(t, _):
         # size-1 slices, not int indices: interpret-mode discharge rejects
-        # raw python ints in pl.load/pl.store index tuples
+        # raw python ints in ref index tuples
         idx = (pl.ds(0, 1), pl.ds(0, 1), pl.ds(t, 1), slice(None))
-        r_t = pl.load(r_ref, idx)[0, 0, 0].astype(jnp.float32)   # (hs,)
-        k_t = pl.load(k_ref, idx)[0, 0, 0].astype(jnp.float32)
-        v_t = pl.load(v_ref, idx)[0, 0, 0].astype(jnp.float32)
-        w_t = pl.load(w_ref, idx)[0, 0, 0].astype(jnp.float32)
+        r_t = r_ref[idx][0, 0, 0].astype(jnp.float32)   # (hs,)
+        k_t = k_ref[idx][0, 0, 0].astype(jnp.float32)
+        v_t = v_ref[idx][0, 0, 0].astype(jnp.float32)
+        w_t = w_ref[idx][0, 0, 0].astype(jnp.float32)
         kv = k_t[:, None] * v_t[None, :]                 # (hs, hs)
         s = s_ref[...]
         y = jnp.sum(r_t[:, None] * (s + u[:, None] * kv), axis=0)
-        pl.store(y_ref, idx, y.astype(y_ref.dtype)[None, None, None])
+        y_ref[idx] = y.astype(y_ref.dtype)[None, None, None]
         s_ref[...] = w_t[:, None] * s + kv
         return 0
 

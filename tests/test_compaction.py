@@ -387,6 +387,42 @@ def test_default_cost_model_prefers_pinned_file(tmp_path, monkeypatch):
     assert got.dispatch_us == 123.0 and got.epoch_lane_us == 0.01
 
 
+def test_default_cost_model_failed_measurement_raises(tmp_path, monkeypatch):
+    """A failed measurement is an error, never the built-in constants;
+    those serve only ``allow_measure=False`` with no cached file."""
+    def broken():
+        raise RuntimeError("probe failed")
+
+    monkeypatch.setenv(costmodel.ENV_PATH, str(tmp_path / "none.json"))
+    monkeypatch.setattr(costmodel, "_CACHE", {})
+    monkeypatch.setattr(costmodel, "measure", broken)
+    with pytest.raises(RuntimeError, match="probe failed"):
+        costmodel.default_cost_model()
+    got = costmodel.default_cost_model(allow_measure=False)
+    assert got.source == "fallback"
+
+
+@pytest.mark.parametrize("env", [None, "elsewhere"])
+def test_enable_compile_cache_path(tmp_path, monkeypatch, env):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
+    otherwise the cache sits at a fixed ``.jax_cache/`` in the checkout."""
+    from repro.core import util
+    set_to = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: set_to.append((k, v)))
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(util._CHECKOUT / ".jax_cache")
+        assert util.enable_compile_cache() == want
+        assert set_to == [("jax_compilation_cache_dir", want)]
+        assert (util._CHECKOUT / "src" / "repro" / "core" /
+                "util.py").is_file()
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env))
+        assert util.enable_compile_cache() == str(tmp_path / env)
+        assert set_to == []
+
+
 # ---------------------------------------------------------------------------
 # Floor validation (ISSUE 10): nonsensical pow2 floors fail loudly
 # ---------------------------------------------------------------------------
