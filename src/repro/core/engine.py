@@ -33,7 +33,7 @@ from .control import (ControlPolicy, DeadlinePolicy, earliest_finish,
                       failover_targets, scenario_control)
 from .telemetry import (EV_FINISH, EV_KILL, EV_PREEMPT, EV_SCALE_CLOSE,
                         EV_SCALE_OPEN, EV_SHED, EV_START, TraceBuffers,
-                        event_capacity, timeseries_capacity)
+                        event_capacity, pull, put, timeseries_capacity)
 from .util import pow2_pad, validate_pow2_floor
 
 _BIG = 1e30          # stand-in for +inf that survives arithmetic
@@ -1291,10 +1291,11 @@ def _step_epoch_chunk_impl(batch: ScenarioArrays, inv: _EpochInv,
                 jax.vmap(partial(_lane_active, control=control))(batch, c2),
                 i + 1)
 
-    carry, act, i = jax.lax.while_loop(cond, body,
-                                       (carry, active, jnp.int32(0)))
-    counts = jnp.stack([i, jnp.sum(act, dtype=jnp.int32)])
-    return carry, act, counts, jnp.argsort(~act)
+    with jax.named_scope("epoch_loop"):
+        carry, act, i = jax.lax.while_loop(cond, body,
+                                           (carry, active, jnp.int32(0)))
+        counts = jnp.stack([i, jnp.sum(act, dtype=jnp.int32)])
+        return carry, act, counts, jnp.argsort(~act)
 
 
 _step_epoch_chunk = jax.jit(_step_epoch_chunk_impl,
@@ -1380,7 +1381,12 @@ def simulate_batch_arrays_compact(
     (full mask/permutation device→host pulls — paid only on rounds that
     actually compact), ``scalar_syncs`` (the per-round fused
     ``[n_step, n_active]`` scalar pulls), ``compactions`` (gather
-    rounds) and ``dispatches`` (chunk-stepper launches).
+    rounds), ``dispatches`` (chunk-stepper launches),
+    ``lane_epochs_allotted`` (launched lanes × the epochs each chunk may
+    run) and the transfer counts of :func:`~repro.core.telemetry.put` /
+    ``pull``.  The host spans ``iotsim.compact.{prepare,step,poll,
+    regather,finish}`` mark the loop's phases on the profiler's clock
+    (DESIGN.md §12.4).
 
     ``donate=True`` routes rounds through the buffer-donating stepper /
     store-scatter jits (carries update in place instead of copying every
@@ -1391,22 +1397,27 @@ def simulate_batch_arrays_compact(
     """
     if control is None:
         control = _control_active(batch)
+    if stats is None:
+        stats = {}
+    for key in ("syncs", "scalar_syncs", "compactions", "dispatches",
+                "lane_epochs_allotted"):
+        stats.setdefault(key, 0)
     N, T = batch.task_job.shape[:2]
     bound = 2 * T + 2
     if control:
         # lanes widen their own epoch bound (_lane_bound, additive per
         # mechanism); the host budget only needs the batch-wide worst
         # case — per-lane counts stay exact through the activity mask
-        if bool(np.any(np.asarray(batch.vm_valid)
-                       & (np.asarray(batch.vm_fail) < _BIG / 2))):
+        if bool(np.any(pull(batch.vm_valid, stats)
+                       & (pull(batch.vm_fail, stats) < _BIG / 2))):
             bound += 2 * T + batch.vm_mips.shape[1]
-        if bool(np.any((np.asarray(batch.deadline_policy)
+        if bool(np.any((pull(batch.deadline_policy, stats)
                         == int(DeadlinePolicy.SHED))
-                       & np.any(np.asarray(batch.task_valid)
-                                & (np.asarray(batch.task_deadline)
+                       & np.any(pull(batch.task_valid, stats)
+                                & (pull(batch.task_deadline, stats)
                                    < _BIG / 2), axis=1))):
             bound += T + 1
-        if bool(np.any(np.asarray(batch.preempt) != 0)):
+        if bool(np.any(pull(batch.preempt, stats) != 0)):
             bound += 2 * T
     if k == "auto":
         from . import costmodel as costmodel_mod
@@ -1419,20 +1430,13 @@ def simulate_batch_arrays_compact(
     validate_pow2_floor(floor)
     tr = _trace_caps(T, batch.vm_mips.shape[1], control, trace,
                      trace_events)
-    if stats is None:
-        stats = {}
-    stats.setdefault("syncs", 0)
-    stats.setdefault("scalar_syncs", 0)
-    stats.setdefault("compactions", 0)
-    stats.setdefault("dispatches", 0)
-    inv, c0 = _setup_batch(batch, control=control, trace=tr)
     loop = _compact_loop_legacy if legacy else _compact_loop_lean
-    return loop(batch, inv, c0, bound=bound, k=k, floor=floor,
-                control=control, tr=tr, stats=stats, donate=donate)
+    return loop(batch, bound=bound, k=k, floor=floor, control=control,
+                tr=tr, stats=stats, donate=donate)
 
 
-def _compact_loop_lean(batch: ScenarioArrays, inv, c0, *, bound: int,
-                       k: int, floor: int, control: bool, tr, stats: dict,
+def _compact_loop_lean(batch: ScenarioArrays, *, bound: int, k: int,
+                       floor: int, control: bool, tr, stats: dict,
                        donate: bool):
     """Dispatch-lean host loop (DESIGN.md §13): one fused scalar pull per
     round; the full active-first permutation crosses the host boundary
@@ -1449,11 +1453,14 @@ def _compact_loop_lean(batch: ScenarioArrays, inv, c0, *, bound: int,
     the final ``_output_batch``/``_trace_of`` reads only the merged
     result, never a donated argument."""
     N = batch.task_job.shape[0]
-    cur_batch, cur_inv, cur_carry = batch, inv, c0
-    cur_active, n_act_dev, order_dev = _activity_batch(batch, c0,
-                                                       control=control)
-    n_act = int(n_act_dev)
-    stats["scalar_syncs"] += 1
+    with jax.profiler.TraceAnnotation("iotsim.compact.prepare"):
+        inv, c0 = _setup_batch(batch, control=control, trace=tr)
+        cur_batch, cur_inv, cur_carry = batch, inv, c0
+        cur_active, n_act_dev, order_dev = _activity_batch(batch, c0,
+                                                           control=control)
+        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
+            n_act = int(pull(n_act_dev, stats))
+        stats["scalar_syncs"] += 1
     carry_store = None
     # freshness flags: ``_epoch_setup``/``initial_state``-style jits can
     # forward an input array unchanged, so the t=0 carry may alias batch
@@ -1473,95 +1480,117 @@ def _compact_loop_lean(batch: ScenarioArrays, inv, c0, *, bound: int,
             # idempotently) into a compacted view of the original batch —
             # the device-computed order crosses the host boundary here
             # and only here
-            order = np.asarray(order_dev)[:pad]
-            stats["syncs"] += 1
-            if carry_store is None:
-                carry_store, store_fresh = cur_carry, carry_fresh
-            else:
-                carry_store = (_put_lanes_donated if donate and store_fresh
-                               else _put_lanes)(carry_store,
-                                                jnp.asarray(cur_idx),
-                                                cur_carry)
-                store_fresh = True
-            cur_idx = cur_idx[order]
-            take = jnp.asarray(cur_idx)
-            cur_batch = _take_lanes(batch, take)
-            cur_inv = _take_lanes(inv, take)
-            cur_carry = _take_lanes(carry_store, take)
-            carry_fresh = True
-            cur_active = _active_batch(cur_batch, cur_carry,
-                                       control=control)
-            stats["compactions"] += 1
+            with jax.profiler.TraceAnnotation("iotsim.compact.regather"):
+                order = pull(order_dev, stats)[:pad]
+                stats["syncs"] += 1
+                if carry_store is None:
+                    carry_store, store_fresh = cur_carry, carry_fresh
+                else:
+                    carry_store = (_put_lanes_donated
+                                   if donate and store_fresh
+                                   else _put_lanes)(carry_store,
+                                                    put(cur_idx, stats),
+                                                    cur_carry)
+                    store_fresh = True
+                cur_idx = cur_idx[order]
+                take = put(cur_idx, stats)
+                cur_batch = _take_lanes(batch, take)
+                cur_inv = _take_lanes(inv, take)
+                cur_carry = _take_lanes(carry_store, take)
+                carry_fresh = True
+                cur_active = _active_batch(cur_batch, cur_carry,
+                                           control=control)
+                stats["compactions"] += 1
         step = (_step_epoch_chunk_donated if donate and carry_fresh
                 else _step_epoch_chunk)
-        cur_carry, cur_active, counts, order_dev = step(
-            cur_batch, cur_inv, cur_carry, cur_active,
-            jnp.int32(bound - realized), k, control=control,
-            trace=tr is not None)
+        limit = min(k, bound - realized)    # epochs the chunk may run
+        stats["lane_epochs_allotted"] += len(cur_idx) * limit
+        with jax.profiler.TraceAnnotation("iotsim.compact.step",
+                                          lanes=len(cur_idx),
+                                          epoch_limit=limit):
+            cur_carry, cur_active, counts, order_dev = step(
+                cur_batch, cur_inv, cur_carry, cur_active,
+                put(np.int32(bound - realized), stats), k, control=control,
+                trace=tr is not None)
         carry_fresh = True
         stats["dispatches"] += 1
-        n_step, n_act = (int(v) for v in np.asarray(counts))
+        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
+            n_step, n_act = (int(v) for v in pull(counts, stats))
         stats["scalar_syncs"] += 1
         realized += n_step
-    if carry_store is None:
-        final = cur_carry
-    else:
-        final = (_put_lanes_donated if donate and store_fresh
-                 else _put_lanes)(carry_store, jnp.asarray(cur_idx),
-                                  cur_carry)
-    out = _output_batch(batch, final), jnp.int32(realized)
-    if tr is not None:
-        return out + (_trace_of(final),)
-    return out
+    with jax.profiler.TraceAnnotation("iotsim.compact.finish"):
+        if carry_store is None:
+            final = cur_carry
+        else:
+            final = (_put_lanes_donated if donate and store_fresh
+                     else _put_lanes)(carry_store, put(cur_idx, stats),
+                                      cur_carry)
+        out = _output_batch(batch, final), put(np.int32(realized), stats)
+        if tr is not None:
+            return out + (_trace_of(final),)
+        return out
 
 
-def _compact_loop_legacy(batch: ScenarioArrays, inv, c0, *, bound: int,
-                         k: int, floor: int, control: bool, tr,
-                         stats: dict, donate: bool):
-    """The pre-dispatch-lean host loop, verbatim: a full ``bool[N]`` mask
-    pull + host-side ordering every round, no donation.  Kept as the
-    honest A/B comparator for the recorded compaction benches and as the
-    reference the lean loop's bitwise tests pin against."""
+def _compact_loop_legacy(batch: ScenarioArrays, *, bound: int, k: int,
+                         floor: int, control: bool, tr, stats: dict,
+                         donate: bool):
+    """The pre-dispatch-lean host loop: a full ``bool[N]`` mask pull +
+    host-side ordering every round, no donation.  Kept as the honest A/B
+    comparator for the recorded compaction benches and as the reference
+    the lean loop's bitwise tests pin against.  Its per-round mask pull
+    sits in the ``iotsim.compact.regather`` span."""
     del donate                     # the legacy loop never donated
     N = batch.task_job.shape[0]
-    carry_store = c0
-    cur_batch, cur_inv, cur_carry = batch, inv, c0
-    cur_active = _active_batch(batch, c0, control=control)
+    with jax.profiler.TraceAnnotation("iotsim.compact.prepare"):
+        inv, c0 = _setup_batch(batch, control=control, trace=tr)
+        carry_store = c0
+        cur_batch, cur_inv, cur_carry = batch, inv, c0
+        cur_active = _active_batch(batch, c0, control=control)
     cur_idx = np.arange(N)
     realized = 0
     while realized < bound:
-        act_np = np.asarray(cur_active)
-        stats["syncs"] += 1
-        n_act = int(act_np.sum())
-        if n_act == 0:
-            break
-        pad = pow2_pad(n_act, cap=len(cur_idx), floor=floor)
-        if pad < len(cur_idx):
-            carry_store = _put_lanes(carry_store, jnp.asarray(cur_idx),
-                                     cur_carry)
-            order = np.concatenate([np.nonzero(act_np)[0],
-                                    np.nonzero(~act_np)[0]])[:pad]
-            cur_idx = cur_idx[order]
-            take = jnp.asarray(cur_idx)
-            cur_batch = _take_lanes(batch, take)
-            cur_inv = _take_lanes(inv, take)
-            cur_carry = _take_lanes(carry_store, take)
-            cur_active = _active_batch(cur_batch, cur_carry,
-                                       control=control)
-            stats["compactions"] += 1
-        cur_carry, cur_active, counts, _ = _step_epoch_chunk(
-            cur_batch, cur_inv, cur_carry, cur_active,
-            jnp.int32(bound - realized), k, control=control,
-            trace=tr is not None)
+        with jax.profiler.TraceAnnotation("iotsim.compact.regather"):
+            act_np = pull(cur_active, stats)
+            stats["syncs"] += 1
+            n_act = int(act_np.sum())
+            if n_act == 0:
+                break
+            pad = pow2_pad(n_act, cap=len(cur_idx), floor=floor)
+            if pad < len(cur_idx):
+                carry_store = _put_lanes(carry_store, put(cur_idx, stats),
+                                         cur_carry)
+                order = np.concatenate([np.nonzero(act_np)[0],
+                                        np.nonzero(~act_np)[0]])[:pad]
+                cur_idx = cur_idx[order]
+                take = put(cur_idx, stats)
+                cur_batch = _take_lanes(batch, take)
+                cur_inv = _take_lanes(inv, take)
+                cur_carry = _take_lanes(carry_store, take)
+                cur_active = _active_batch(cur_batch, cur_carry,
+                                           control=control)
+                stats["compactions"] += 1
+        limit = min(k, bound - realized)    # epochs the chunk may run
+        stats["lane_epochs_allotted"] += len(cur_idx) * limit
+        with jax.profiler.TraceAnnotation("iotsim.compact.step",
+                                          lanes=len(cur_idx),
+                                          epoch_limit=limit):
+            cur_carry, cur_active, counts, _ = _step_epoch_chunk(
+                cur_batch, cur_inv, cur_carry, cur_active,
+                put(np.int32(bound - realized), stats), k, control=control,
+                trace=tr is not None)
         stats["dispatches"] += 1
-        n_step = int(counts[0])
+        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
+            n_step = int(pull(counts[0], stats))
         stats["scalar_syncs"] += 1
         realized += n_step
-    carry_store = _put_lanes(carry_store, jnp.asarray(cur_idx), cur_carry)
-    out = _output_batch(batch, carry_store), jnp.int32(realized)
-    if tr is not None:
-        return out + (_trace_of(carry_store),)
-    return out
+    with jax.profiler.TraceAnnotation("iotsim.compact.finish"):
+        carry_store = _put_lanes(carry_store, put(cur_idx, stats),
+                                 cur_carry)
+        out = (_output_batch(batch, carry_store),
+               put(np.int32(realized), stats))
+        if tr is not None:
+            return out + (_trace_of(carry_store),)
+        return out
 
 
 # ---------------------------------------------------------------------------

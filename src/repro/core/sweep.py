@@ -41,6 +41,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import inspect
+import itertools
 import time
 from functools import lru_cache, partial
 from typing import Any, Mapping, Sequence
@@ -69,6 +70,7 @@ from .storage import Placement, StorageSpec, as_placement
 
 _DEFAULT_STORAGE = StorageSpec()    # encode_cell defaults == Scenario's
 _DEFAULT_ELASTICITY = ElasticitySpec()
+_RUN_IDS = itertools.count()        # ``run`` attribute of the iotsim.* spans
 
 
 # ---------------------------------------------------------------------------
@@ -400,8 +402,8 @@ def _validate_cell_columns(cols: Mapping[str, Any]) -> None:
 
 def grid_arrays(params: dict[str, np.ndarray], *, pad_tasks: int,
                 pad_vms: int,
-                static_params: Mapping[str, int] | None = None
-                ) -> ScenarioArrays:
+                static_params: Mapping[str, int] | None = None,
+                stats: dict | None = None) -> ScenarioArrays:
     """vmap :func:`encode_cell` over equal-length parameter arrays.
 
     Each value is ``[N]`` (one scalar per cell) or ``[N, pad_vms]``
@@ -416,6 +418,9 @@ def grid_arrays(params: dict[str, np.ndarray], *, pad_tasks: int,
     lowering, letting XLA dead-code-eliminate the unused binding
     strategies (the sequential LEAST_LOADED load scan dominates encode
     time when it can't be eliminated).
+
+    ``stats`` (a dict, mutated in place) counts the columns uploaded
+    (:func:`telemetry.put`).
     """
     names = list(params)
     static = tuple(sorted((static_params or {}).items()))
@@ -466,7 +471,9 @@ def grid_arrays(params: dict[str, np.ndarray], *, pad_tasks: int,
             f"length; {names[0]!r} has length {n0} but " + ", ".join(bad))
     _validate_cell_columns(params)
     encoder = _grid_encoder(tuple(names), pad_tasks, pad_vms, static)
-    return encoder(*(jnp.asarray(params[n]) for n in names))
+    with jax.profiler.TraceAnnotation("iotsim.upload"):
+        args = [telemetry.put(params[n], stats) for n in names]
+    return encoder(*args)
 
 
 @lru_cache(maxsize=None)
@@ -478,7 +485,8 @@ def _grid_encoder(names: tuple[str, ...], pad_tasks: int, pad_vms: int,
     def one(*xs):
         kw = dict(zip(names, xs))
         kw.update(static)
-        return encode_cell(**kw, pad_tasks=pad_tasks, pad_vms=pad_vms)
+        with jax.named_scope("encode"):
+            return encode_cell(**kw, pad_tasks=pad_tasks, pad_vms=pad_vms)
     return jax.jit(jax.vmap(one))
 
 
@@ -939,7 +947,22 @@ class SweepPlan:
         every metric value are unchanged.  Composes with every mode
         (streaming returns ``(StreamedSweep, RunReport)``; each streamed
         chunk re-buckets, so its report holds one entry per bucket *per
-        chunk*).
+        chunk*).  Its counters also give the explicit host↔device
+        transfers (``h2d_*``/``d2h_*``) and the lane-epochs the launches
+        allotted against those the cells needed.
+
+        Every call marks its phases with host spans on the JAX profiler's
+        clock (``jax.profiler.TraceAnnotation``, DESIGN.md §12.4):
+        ``iotsim.run`` (attributes ``run``, ``cells``, ``backend``,
+        ``compact``) holds ``iotsim.plan``, ``iotsim.assemble`` and one
+        ``iotsim.bucket`` per bucket, which holds ``iotsim.upload``,
+        ``iotsim.launch``, ``iotsim.readback`` and, under compaction,
+        ``iotsim.metrics`` and ``iotsim.compact.{prepare, step, poll,
+        regather, finish}``.  With no profiler running each costs a
+        no-op.  To see them, run the sweep inside ``with
+        jax.profiler.trace(log_dir):`` and open the trace in Perfetto or
+        TensorBoard: each idle gap of the device then lies under the
+        phase that held it.
         """
         if mesh is not None and chunk is not None:
             raise ValueError("run: pass mesh or chunk, not both")
@@ -961,38 +984,47 @@ class SweepPlan:
             t0 = time.perf_counter()
             ci0, ei0 = _cache_infos()
             buckets = []
-        if stream_to is not None:
-            if chunk is None:
-                raise ValueError(
-                    "run: stream_to= needs chunk= (the streamed write "
-                    "appends one chunk of cells at a time)")
-            streamed = self._run_streaming(stream_to, chunk, bucket,
-                                           backend, compact, cost_model,
-                                           buckets)
-            if buckets is None:
-                return streamed
-            return streamed, _finish_report(buckets, self.size, backend,
-                                            compact, cost_model, ci0, ei0,
-                                            t0)
-        cols, pad_tasks, pad_vms = self._compiled()
-        metrics, n_jobs = _execute_grid(cols, self.size, pad_tasks, pad_vms,
-                                        bucket, mesh, chunk, backend,
-                                        compact, cost_model, report=buckets)
-        shaped = {
-            name: (m.reshape(self.shape) if m.ndim == 1 or n_jobs == 1
-                   else m.reshape(self.shape + (n_jobs,)))
-            for name, m in metrics.items()}
-        result = SweepResult(axis_names=tuple(d.names for d in self.dims),
-                             axis_labels=tuple(d.labels for d in self.dims),
-                             metrics=shaped, n_jobs=n_jobs)
+        run_id = next(_RUN_IDS)
+        with jax.profiler.TraceAnnotation("iotsim.run", run=run_id,
+                                          cells=self.size, backend=backend,
+                                          compact=str(compact)):
+            if stream_to is not None:
+                if chunk is None:
+                    raise ValueError(
+                        "run: stream_to= needs chunk= (the streamed write "
+                        "appends one chunk of cells at a time)")
+                streamed = self._run_streaming(stream_to, chunk, bucket,
+                                               backend, compact, cost_model,
+                                               buckets, run_id)
+                if buckets is None:
+                    return streamed
+                return streamed, _finish_report(buckets, self.size, backend,
+                                                compact, cost_model, ci0,
+                                                ei0, t0)
+            with jax.profiler.TraceAnnotation("iotsim.plan"):
+                cols, pad_tasks, pad_vms = self._compiled()
+            metrics, n_jobs = _execute_grid(cols, self.size, pad_tasks,
+                                            pad_vms, bucket, mesh, chunk,
+                                            backend, compact, cost_model,
+                                            report=buckets, run_id=run_id)
+            with jax.profiler.TraceAnnotation("iotsim.assemble"):
+                shaped = {
+                    name: (m.reshape(self.shape)
+                           if m.ndim == 1 or n_jobs == 1
+                           else m.reshape(self.shape + (n_jobs,)))
+                    for name, m in metrics.items()}
+                result = SweepResult(
+                    axis_names=tuple(d.names for d in self.dims),
+                    axis_labels=tuple(d.labels for d in self.dims),
+                    metrics=shaped, n_jobs=n_jobs)
         if buckets is None:
             return result
         return result, _finish_report(buckets, self.size, backend, compact,
                                       cost_model, ci0, ei0, t0)
 
     def _run_streaming(self, path, chunk: int, bucket, backend,
-                       compact=None, cost=None,
-                       report=None) -> "StreamedSweep":
+                       compact=None, cost=None, report=None,
+                       run_id: int = 0) -> "StreamedSweep":
         """Chunked execute + parquet append (see :meth:`run`)."""
         try:
             import pyarrow as pa
@@ -1002,7 +1034,8 @@ class SweepPlan:
                 "run(stream_to=...) requires the optional pyarrow "
                 "dependency (pip install pyarrow); without it use "
                 "run(chunk=...) and to_table()") from e
-        cols, pad_tasks, pad_vms = self._compiled()
+        with jax.profiler.TraceAnnotation("iotsim.plan"):
+            cols, pad_tasks, pad_vms = self._compiled()
         N, shape = self.size, self.shape
         axis_names = tuple(d.names for d in self.dims)
         axis_labels = tuple(d.labels for d in self.dims)
@@ -1013,7 +1046,7 @@ class SweepPlan:
                 sub = {k: v[lo:hi] for k, v in cols.items()}
                 metrics, n_jobs = _execute_grid(
                     sub, hi - lo, pad_tasks, pad_vms, bucket, None, None,
-                    backend, compact, cost, report=report)
+                    backend, compact, cost, report=report, run_id=run_id)
                 table = pa.table(_long_form_columns(
                     axis_names, axis_labels, shape, metrics, n_jobs,
                     lo, hi))
@@ -1069,6 +1102,7 @@ def _finish_report(buckets, n_cells: int, backend, compact, cost,
         compaction_syncs=sum(b.compact_syncs for b in buckets),
         scalar_syncs=sum(b.compact_scalar_syncs for b in buckets),
         dispatches=sum(b.dispatches for b in buckets),
+        **{f: sum(getattr(b, f) for b in buckets) for f in _COUNTERS},
         cost_model={"dispatch_us": cost.dispatch_us,
                     "epoch_lane_us": cost.epoch_lane_us,
                     "sync_us": cost.sync_us,
@@ -1078,26 +1112,39 @@ def _finish_report(buckets, n_cells: int, backend, compact, cost,
         wall_s=time.perf_counter() - t0)
 
 
+# Transfer and lane-epoch counters: ``stats`` keys of the drivers and
+# fields of BucketReport / RunReport under the same names.
+_COUNTERS = ("h2d_transfers", "h2d_bytes", "d2h_transfers", "d2h_bytes",
+             "lane_epochs_allotted", "lane_epochs_useful")
+
+
 def _execute_grid(cols: dict[str, np.ndarray], N: int, pad_tasks: int,
                   pad_vms: int, bucket, mesh, chunk, backend,
-                  compact=None, cost=None, report: list | None = None
-                  ) -> tuple[dict[str, np.ndarray], int]:
+                  compact=None, cost=None, report: list | None = None,
+                  run_id: int = 0) -> tuple[dict[str, np.ndarray], int]:
     """Bucket + simulate ``N`` flattened cells; returns ``(metrics,
     n_jobs)`` with per-job metric columns shaped ``[N, n_jobs]`` and
     per-scenario columns ``[N]`` (callers reshape to grid/table form).
     ``report`` (a list, appended in place) collects one
-    :class:`telemetry.BucketReport` per dispatched bucket."""
+    :class:`telemetry.BucketReport` per dispatched bucket; ``run_id``
+    tags the ``iotsim.bucket`` spans with their run."""
     if (compact is not None or report is not None) and cost is None:
         cost = costmodel_mod.default_cost_model()
-    groups = _bucket_groups(cols, pad_tasks, pad_vms, bucket, cost)
+    with jax.profiler.TraceAnnotation("iotsim.plan"):
+        groups = _bucket_groups(cols, pad_tasks, pad_vms, bucket, cost)
     parts = []
-    for idx, gcols, statics, tb, vb in groups:
+    for b, (idx, gcols, statics, tb, vb) in enumerate(groups):
         stats = {"dispatches": 0, "syncs": 0, "scalar_syncs": 0,
-                 "compactions": 0}
+                 "compactions": 0, **dict.fromkeys(_COUNTERS, 0)}
         w0 = time.perf_counter()
-        parts.append((idx, *_run_cells(gcols, len(idx), tb, vb, statics,
-                                       mesh, chunk, backend, compact, cost,
-                                       stats=stats)))
+        with jax.profiler.TraceAnnotation("iotsim.bucket", run=run_id,
+                                          bucket=b, cells=len(idx),
+                                          pad_tasks=tb, pad_vms=vb):
+            part = _run_cells(gcols, len(idx), tb, vb, statics, mesh,
+                              chunk, backend, compact, cost, stats=stats)
+        parts.append((idx, *part))
+        stats["lane_epochs_useful"] = int(np.sum(part[1].n_epochs,
+                                                 dtype=np.int64))
         if report is not None:
             report.append(telemetry.BucketReport(
                 cells=len(idx), pad_tasks=tb, pad_vms=vb, backend=backend,
@@ -1112,24 +1159,26 @@ def _execute_grid(cols: dict[str, np.ndarray], N: int, pad_tasks: int,
                 dispatches=stats["dispatches"],
                 compact_syncs=stats["syncs"],
                 compact_scalar_syncs=stats["scalar_syncs"],
-                wall_s=time.perf_counter() - w0))
-    n_jobs = int(parts[0][1].makespan.shape[-1])
-    metrics: dict[str, np.ndarray] = {}
-    for f in JobMetrics._fields:
-        out = np.empty((N, n_jobs),
-                       np.asarray(getattr(parts[0][1], f)).dtype)
-        for idx, jm, _, _ in parts:
-            out[idx] = np.asarray(getattr(jm, f))
-        metrics[f] = out
-    for f in ScenarioMetrics._fields:
-        out = np.empty(N, np.asarray(getattr(parts[0][2], f)).dtype)
-        for idx, _, sm, _ in parts:
-            out[idx] = np.asarray(getattr(sm, f))
-        metrics[f] = out
-    realized = np.empty(N, np.int32)
-    for idx, _, _, rz in parts:
-        realized[idx] = rz
-    metrics["realized_epochs"] = realized
+                wall_s=time.perf_counter() - w0,
+                **{f: stats[f] for f in _COUNTERS}))
+    with jax.profiler.TraceAnnotation("iotsim.assemble"):
+        n_jobs = int(parts[0][1].makespan.shape[-1])
+        metrics: dict[str, np.ndarray] = {}
+        for f in JobMetrics._fields:
+            out = np.empty((N, n_jobs),
+                           np.asarray(getattr(parts[0][1], f)).dtype)
+            for idx, jm, _, _ in parts:
+                out[idx] = np.asarray(getattr(jm, f))
+            metrics[f] = out
+        for f in ScenarioMetrics._fields:
+            out = np.empty(N, np.asarray(getattr(parts[0][2], f)).dtype)
+            for idx, _, sm, _ in parts:
+                out[idx] = np.asarray(getattr(sm, f))
+            metrics[f] = out
+        realized = np.empty(N, np.int32)
+        for idx, _, _, rz in parts:
+            realized[idx] = rz
+        metrics["realized_epochs"] = realized
     return metrics, n_jobs
 
 
@@ -1307,26 +1356,34 @@ def _fused_runner(names: tuple[str, ...], pad_tasks: int, pad_vms: int,
             kw.update(static_kw)
             return encode_cell(**kw, pad_tasks=pad_tasks, pad_vms=pad_vms)
 
-        batch = jax.vmap(one)(*xs)
-        if backend == "pallas":
-            from repro.kernels.mr_sched import \
-                epoch_schedule  # lazy: ref.py cycle
-            out = epoch_schedule(batch, max_pes=max_pes, control=control)
-            realized = jnp.max(out.n_epochs)
-        else:
-            out, realized = simulate_batch_arrays(batch, control=control)
-        return (jax.vmap(job_metrics)(batch, out),
-                jax.vmap(scenario_metrics)(batch, out), realized)
+        with jax.named_scope("encode"):
+            batch = jax.vmap(one)(*xs)
+        with jax.named_scope("epoch_loop"):
+            if backend == "pallas":
+                from repro.kernels.mr_sched import \
+                    epoch_schedule  # lazy: ref.py cycle
+                out = epoch_schedule(batch, max_pes=max_pes, control=control)
+                realized = jnp.max(out.n_epochs)
+            else:
+                out, realized = simulate_batch_arrays(batch, control=control)
+        return _metrics_of(batch, out) + (realized,)
 
     return jax.jit(run)
+
+
+def _metrics_of(batch, out):
+    """Job and scenario metrics of a simulated batch (traced inside the
+    bucket runner and the compacted path's metrics pass)."""
+    with jax.named_scope("metrics"):
+        return (jax.vmap(job_metrics)(batch, out),
+                jax.vmap(scenario_metrics)(batch, out))
 
 
 @jax.jit
 def _metrics_batch(batch, out):
     """Fused metrics pass for the compacted path (its epoch stepping is
     host-driven, so metrics dispatch separately from simulation)."""
-    return (jax.vmap(job_metrics)(batch, out),
-            jax.vmap(scenario_metrics)(batch, out))
+    return _metrics_of(batch, out)
 
 
 def _run_compact(cols: dict[str, np.ndarray], pad_tasks: int, pad_vms: int,
@@ -1340,7 +1397,7 @@ def _run_compact(cols: dict[str, np.ndarray], pad_tasks: int, pad_vms: int,
     compaction needs host control flow over the active-lane count (XLA
     shapes are static)."""
     batch = grid_arrays(cols, pad_tasks=pad_tasks, pad_vms=pad_vms,
-                        static_params=statics)
+                        static_params=statics, stats=stats)
     if backend == "pallas":
         from repro.kernels.mr_sched import \
             epoch_schedule_compact  # lazy: ref.py cycle
@@ -1352,8 +1409,10 @@ def _run_compact(cols: dict[str, np.ndarray], pad_tasks: int, pad_vms: int,
                                                       cost_model=cost,
                                                       control=control,
                                                       stats=stats)
-    jm, sm = _metrics_batch(batch, out)
-    return jm, sm, int(realized)
+    with jax.profiler.TraceAnnotation("iotsim.metrics"):
+        jm, sm = _metrics_batch(batch, out)
+    with jax.profiler.TraceAnnotation("iotsim.readback"):
+        return jm, sm, int(telemetry.pull(realized, stats))
 
 
 def _run_cells(cols: dict[str, np.ndarray], n: int, pad_tasks: int,
@@ -1363,11 +1422,18 @@ def _run_cells(cols: dict[str, np.ndarray], n: int, pad_tasks: int,
                    JobMetrics, ScenarioMetrics, np.ndarray]:
     """Encode + simulate one bucket's cells; returns host-side
     ``(JobMetrics, ScenarioMetrics, realized_epochs[n])``.  ``stats``
-    (a dict, mutated in place) counts device ``dispatches`` plus the
-    compact drivers' host ``syncs``/``compactions``."""
+    (a dict, mutated in place) counts device ``dispatches``, the compact
+    drivers' host ``syncs``/``compactions``, the transfers
+    (:func:`telemetry.put`/:func:`telemetry.pull`) and
+    ``lane_epochs_allotted``."""
     if stats is None:
         stats = {}
     stats.setdefault("dispatches", 0)
+    stats.setdefault("lane_epochs_allotted", 0)
+
+    def readback(tree):
+        return jax.tree.map(lambda x: telemetry.pull(x, stats), tree)
+
     # the control path is keyed on column *presence* (host-decidable even
     # for traced columns — engine._control_active is not, under trace):
     # a plan that never names a control parameter pays zero control cost
@@ -1378,13 +1444,16 @@ def _run_cells(cols: dict[str, np.ndarray], n: int, pad_tasks: int,
         n_dev = int(mesh.devices.size)
         full = -(-n // n_dev) * n_dev
         batch = grid_arrays(_pad_cells(cols, full), pad_tasks=pad_tasks,
-                            pad_vms=pad_vms, static_params=statics)
-        jm, sm = _simulate_full_sharded(batch, mesh, control)
+                            pad_vms=pad_vms, static_params=statics,
+                            stats=stats)
+        with jax.profiler.TraceAnnotation("iotsim.launch"):
+            jm, sm = _simulate_full_sharded(batch, mesh, control)
         stats["dispatches"] += 1
-        jm = jax.tree.map(lambda x: np.asarray(x)[:n], jm)
-        sm = jax.tree.map(lambda x: np.asarray(x)[:n], sm)
-        realized = np.full(n, int(np.max(sm.n_epochs)), np.int32)
-        return jm, sm, realized
+        with jax.profiler.TraceAnnotation("iotsim.readback"):
+            jm, sm = jax.tree.map(lambda x: x[:n], readback((jm, sm)))
+        realized = int(np.max(sm.n_epochs))
+        stats["lane_epochs_allotted"] += full * realized
+        return jm, sm, np.full(n, realized, np.int32)
     max_pes = (max(int(np.ceil(float(np.max(cols["vm_pes"])))), 1)
                if backend == "pallas" else 0)
     if compact is not None:
@@ -1398,39 +1467,60 @@ def _run_cells(cols: dict[str, np.ndarray], n: int, pad_tasks: int,
                 jm, sm, rz = _run_compact(part, pad_tasks, pad_vms, statics,
                                           backend, compact, cost, max_pes,
                                           control, stats)
-                parts.append(jax.tree.map(lambda x: np.asarray(x)[:take],
-                                          (jm, sm)))
+                with jax.profiler.TraceAnnotation("iotsim.readback"):
+                    parts.append(jax.tree.map(lambda x: x[:take],
+                                              readback((jm, sm))))
                 realized[lo:lo + take] = rz
             jm, sm = jax.tree.map(lambda *xs: np.concatenate(xs), *parts)
             return jm, sm, realized
         jm, sm, rz = _run_compact(cols, pad_tasks, pad_vms, statics,
                                   backend, compact, cost, max_pes, control,
                                   stats)
-        jm = jax.tree.map(np.asarray, jm)
-        sm = jax.tree.map(np.asarray, sm)
+        with jax.profiler.TraceAnnotation("iotsim.readback"):
+            jm, sm = readback((jm, sm))
         return jm, sm, np.full(n, rz, np.int32)
     names = tuple(sorted(cols))
     runner = _fused_runner(names, pad_tasks, pad_vms,
                            tuple(sorted((statics or {}).items())),
                            backend, max_pes, control)
+
+    def launch(part):
+        """Upload, run and read back one batch of cells; ``(jm, sm,
+        realized)`` on the host."""
+        with jax.profiler.TraceAnnotation("iotsim.upload"):
+            args = [telemetry.put(part[k], stats) for k in names]
+        with jax.profiler.TraceAnnotation("iotsim.launch"):
+            jm, sm, rz = runner(*args)
+        stats["dispatches"] += 1
+        with jax.profiler.TraceAnnotation("iotsim.readback"):
+            jm, sm = readback((jm, sm))
+            rz = int(telemetry.pull(rz, stats))
+        stats["lane_epochs_allotted"] += _lanes(len(args[0]), backend) * rz
+        return jm, sm, rz
+
     if chunk is not None:
         parts, realized = [], np.empty(n, np.int32)
         for lo in range(0, n, chunk):
             part = _pad_cells({k: v[lo:lo + chunk] for k, v in cols.items()},
                               min(chunk, n))
             take = min(chunk, n - lo)
-            jm, sm, rz = runner(*(jnp.asarray(part[k]) for k in names))
-            stats["dispatches"] += 1
-            parts.append(jax.tree.map(lambda x: np.asarray(x)[:take],
-                                      (jm, sm)))
-            realized[lo:lo + take] = int(rz)
+            jm, sm, rz = launch(part)
+            parts.append(jax.tree.map(lambda x: x[:take], (jm, sm)))
+            realized[lo:lo + take] = rz
         jm, sm = jax.tree.map(lambda *xs: np.concatenate(xs), *parts)
         return jm, sm, realized
-    jm, sm, rz = runner(*(jnp.asarray(cols[k]) for k in names))
-    stats["dispatches"] += 1
-    jm = jax.tree.map(np.asarray, jm)
-    sm = jax.tree.map(np.asarray, sm)
-    return jm, sm, np.full(n, int(rz), np.int32)
+    jm, sm, rz = launch(cols)
+    return jm, sm, np.full(n, rz, np.int32)
+
+
+def _lanes(n: int, backend: str) -> int:
+    """Lanes a dense launch of ``n`` cells steps: the Pallas kernel pads
+    the batch to whole tiles (``ops.lane_pad``), the XLA engine steps the
+    batch as given."""
+    if backend != "pallas":
+        return n
+    from repro.kernels.mr_sched import ops  # lazy: ref.py cycle
+    return n + ops.lane_pad(n, ops.resolve_mode(None, None)[1])
 
 
 def _plain_label(v):

@@ -23,7 +23,8 @@ Three surfaces, all opt-in (the plain path pays zero ops):
 * **Sweep-runtime telemetry** — :class:`RunReport`
   (``SweepPlan.run(report=True)``): bucket decisions with cost-model
   split gains, compile-cache hits/misses, compaction sync counts,
-  per-bucket dispatch counts and wall time, plus device/backend/
+  per-bucket dispatch, transfer and lane-epoch counts and wall time
+  (:func:`put`/:func:`pull` count the transfers), plus device/backend/
   cost-calibration meta and the run-provenance stamp every exported
   artifact carries.
 
@@ -343,6 +344,12 @@ class BucketReport:
     #                                  only on rounds that compact)
     compact_scalar_syncs: int = 0    # per-round fused scalar pulls
     wall_s: float = 0.0              # wall time executing this bucket
+    h2d_transfers: int = 0           # arrays uploaded explicitly
+    h2d_bytes: int = 0
+    d2h_transfers: int = 0           # blocking device->host pulls
+    d2h_bytes: int = 0
+    lane_epochs_allotted: int = 0    # per launch: lanes x epochs it may run
+    lane_epochs_useful: int = 0      # sum of the real cells' n_epochs
 
 
 @dataclasses.dataclass
@@ -360,6 +367,12 @@ class RunReport:
     compaction_syncs: int            # total full mask/permutation pulls
     scalar_syncs: int                # total per-round scalar pulls
     dispatches: int                  # total device dispatches
+    h2d_transfers: int               # totals of the BucketReport fields
+    h2d_bytes: int
+    d2h_transfers: int
+    d2h_bytes: int
+    lane_epochs_allotted: int
+    lane_epochs_useful: int
     cost_model: dict                 # measured coefficients + provenance
     #                                  {dispatch_us, epoch_lane_us, sync_us,
     #                                   device,
@@ -371,6 +384,28 @@ class RunReport:
     def to_json(self, indent: int | None = None) -> str:
         return json.dumps(dataclasses.asdict(self), indent=indent,
                           default=str)
+
+
+def put(x, stats: dict | None):
+    """``jnp.asarray(x)``; a host array counts as one upload in ``stats``
+    (``h2d_transfers``/``h2d_bytes``), a device array as none."""
+    import jax
+    import jax.numpy as jnp
+    a = jnp.asarray(x)
+    if stats is not None and not isinstance(x, jax.Array):
+        stats["h2d_transfers"] = stats.get("h2d_transfers", 0) + 1
+        stats["h2d_bytes"] = stats.get("h2d_bytes", 0) + a.nbytes
+    return a
+
+
+def pull(x, stats: dict | None) -> np.ndarray:
+    """``np.asarray(x)`` of a device value: one blocking device-to-host
+    pull, counted in ``stats`` (``d2h_transfers``/``d2h_bytes``)."""
+    a = np.asarray(x)
+    if stats is not None:
+        stats["d2h_transfers"] = stats.get("d2h_transfers", 0) + 1
+        stats["d2h_bytes"] = stats.get("d2h_bytes", 0) + a.nbytes
+    return a
 
 
 # ---------------------------------------------------------------------------

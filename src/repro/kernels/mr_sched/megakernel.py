@@ -825,6 +825,7 @@ def _mr_epoch_impl(task_len, task_vm, ready0, is_red, valid, shuffle,
         out_specs=state_specs,
         out_shape=state_shapes,
         interpret=interpret,
+        name="mr_epoch",
     )(*data, *state_in)
     return out
 

@@ -21,7 +21,7 @@ from repro.core import network, storage
 from repro.core.control import failover_targets
 from repro.core.engine import (ScenarioArrays, SimOutput, _take_lanes,
                                _put_lanes, _put_lanes_donated)
-from repro.core.telemetry import timeseries_capacity
+from repro.core.telemetry import pull, put, timeseries_capacity
 from repro.core.util import pow2_pad, validate_pow2_floor
 
 from .kernel import mr_schedule
@@ -89,6 +89,12 @@ def resolve_mode(interpret: bool | None, tile: int | None):
     if tile is None:
         tile = INTERPRET_TILE if interpret else COMPILED_TILE
     return interpret, tile
+
+
+def lane_pad(n: int, tile: int) -> int:
+    """Empty lanes that pad ``n`` lanes to whole ``tile``-lane blocks (a
+    batch smaller than a tile is one block of its own size)."""
+    return (-n) % min(tile, max(n, 1))
 
 
 def schedule(batch: ScenarioArrays, *, tile: int = 64,
@@ -172,7 +178,7 @@ def epoch_schedule(batch: ScenarioArrays, *, tile: int | None = None,
             max_pes = max(int(np.ceil(float(jnp.max(batch.vm_pes)))), 1)
     task_len, ready0, shuffle = _derived_inputs(batch)
     N = task_len.shape[0]
-    n_pad = (-N) % min(tile, max(N, 1))
+    n_pad = lane_pad(N, tile)
 
     def pad(x):
         widths = ((0, n_pad),) + ((0, 0),) * (x.ndim - 1)
@@ -309,9 +315,13 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
     with the engine compact driver's keys — ``syncs`` (full permutation
     device→host pulls, paid only on rounds that actually compact),
     ``scalar_syncs`` (the per-round still-active scalar pulls),
-    ``compactions`` (gather/scatter re-tiles) and ``dispatches`` (kernel
-    chunk launches) — feeding the sweep
-    :class:`~repro.core.telemetry.RunReport`.
+    ``compactions`` (gather/scatter re-tiles), ``dispatches`` (kernel
+    chunk launches), ``lane_epochs_allotted`` (launched lanes ×
+    ``epoch_limit``, per chunk) and the transfer counts of
+    :func:`~repro.core.telemetry.put`/``pull`` — feeding the sweep
+    :class:`~repro.core.telemetry.RunReport`.  The host spans
+    ``iotsim.compact.{prepare,step,poll,regather,finish}`` mark the
+    loop's phases on the profiler's clock (DESIGN.md §12.4).
 
     ``donate=True`` steps chunks through the state-donating kernel jit
     (``mr_epoch_donated``) and the donating store-scatter, so the carry
@@ -325,10 +335,12 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
     stats.setdefault("scalar_syncs", 0)
     stats.setdefault("compactions", 0)
     stats.setdefault("dispatches", 0)
+    stats.setdefault("lane_epochs_allotted", 0)
     validate_pow2_floor(floor)
     interpret, tile = resolve_mode(interpret, tile)
     if max_pes is None:
-        max_pes = max(int(np.ceil(float(jnp.max(batch.vm_pes)))), 1)
+        max_pes = max(int(np.ceil(float(pull(jnp.max(batch.vm_pes),
+                                             stats)))), 1)
     N, T = batch.task_vm.shape
     V = batch.vm_mips.shape[1]
     # host budget = the batch-wide worst case of the additive per-lane
@@ -336,15 +348,15 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
     # per-lane counts stay exact through the kernel's lane_bound
     bound = 2 * T + 2
     if control:
-        if bool(np.any(np.asarray(batch.vm_valid)
-                       & (np.asarray(batch.vm_fail) < _BIG / 2))):
+        if bool(np.any(pull(batch.vm_valid, stats)
+                       & (pull(batch.vm_fail, stats) < _BIG / 2))):
             bound += 2 * T + V
-        if bool(np.any((np.asarray(batch.deadline_policy) == 1)
-                       & np.any(np.asarray(batch.task_valid)
-                                & (np.asarray(batch.task_deadline)
+        if bool(np.any((pull(batch.deadline_policy, stats) == 1)
+                       & np.any(pull(batch.task_valid, stats)
+                                & (pull(batch.task_deadline, stats)
                                    < _BIG / 2), axis=1))):
             bound += T + 1
-        if bool(np.any(np.asarray(batch.preempt) != 0)):
+        if bool(np.any(pull(batch.preempt, stats) != 0)):
             bound += 2 * T
     if k == "auto":
         from repro.core import costmodel as costmodel_mod
@@ -353,56 +365,58 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
     k = int(k)
     if k < 1:
         raise ValueError(f"epoch_schedule_compact: k must be >= 1, got {k}")
-    task_len, ready0, shuffle = _derived_inputs(batch)
-    n_pad = (-N) % min(tile, max(N, 1))
+    with jax.profiler.TraceAnnotation("iotsim.compact.prepare"):
+        task_len, ready0, shuffle = _derived_inputs(batch)
+        n_pad = lane_pad(N, tile)
 
-    def pad(x):     # pad lanes hold no valid tasks -> inactive from t=0
-        widths = ((0, n_pad),) + ((0, 0),) * (x.ndim - 1)
-        return jnp.pad(x, widths)
+        def pad(x):     # pad lanes hold no valid tasks -> inactive from t=0
+            widths = ((0, n_pad),) + ((0, 0),) * (x.ndim - 1)
+            return jnp.pad(x, widths)
 
-    lanes = (pad(task_len.astype(jnp.float32)),
-             pad(batch.task_vm.astype(jnp.int32)),
-             pad(batch.task_is_reduce.astype(jnp.int32)),
-             pad(batch.task_valid.astype(jnp.int32)),
-             pad(shuffle.astype(jnp.float32)[:, None]),
-             pad(batch.vm_mips.astype(jnp.float32)),
-             pad(batch.vm_pes.astype(jnp.float32)),
-             pad(batch.sched_policy.astype(jnp.int32)[:, None]),
-             pad(batch.vm_start.astype(jnp.float32)),
-             pad(batch.vm_stop.astype(jnp.float32)),
-             pad(batch.spinup_delay.astype(jnp.float32)[:, None]),
-             pad(batch.task_prio.astype(jnp.float32)))
-    if control:
-        lanes = lanes + _control_lane_data(batch, pad,
-                                           *_control_derived(batch))
-    elif trace:
-        # vm_valid joins the lane data (and the gather) — positionally
-        # the next mr_epoch arg after prio
-        lanes = lanes + (pad(batch.vm_valid.astype(jnp.int32)),)
-    cur_state = initial_state(lanes[0], pad(ready0.astype(jnp.float32)),
-                              lanes[2], lanes[3],
-                              vm_start=lanes[8], vm_stop=lanes[9],
-                              vm_auto=lanes[15] if control else None,
-                              trace_capacity=(timeseries_capacity(
-                                  T, V, control) if trace else None))
-    # ``store`` is None until the first compaction (before that,
-    # ``cur_state`` IS the dense store in original lane order) — the
-    # engine lean loop's store-merge invariant, which is what makes
-    # donating ``cur_state`` into each chunk safe: no N-sized alias of
-    # the donated carry ever exists on the host side.  The freshness
-    # flags guard the other aliasing hazard: ``initial_state`` forwards
-    # some lane arrays as state leaves unchanged (state[1] IS task_len),
-    # and donating a buffer that also rides in the same call's lane
-    # operands is an XLA error — so only carries/stores produced by a
-    # compute op inside this loop are ever donated.
-    store = None
-    state_fresh = store_fresh = False
-    cur_idx = np.arange(N + n_pad)
-    cur_lanes = lanes
-    n_act_dev, order_dev = _state_activity(
-        cur_lanes[3], cur_state[4], cur_state[12] if control else None)
-    n_act = int(n_act_dev)
-    stats["scalar_syncs"] += 1
+        lanes = (pad(task_len.astype(jnp.float32)),
+                 pad(batch.task_vm.astype(jnp.int32)),
+                 pad(batch.task_is_reduce.astype(jnp.int32)),
+                 pad(batch.task_valid.astype(jnp.int32)),
+                 pad(shuffle.astype(jnp.float32)[:, None]),
+                 pad(batch.vm_mips.astype(jnp.float32)),
+                 pad(batch.vm_pes.astype(jnp.float32)),
+                 pad(batch.sched_policy.astype(jnp.int32)[:, None]),
+                 pad(batch.vm_start.astype(jnp.float32)),
+                 pad(batch.vm_stop.astype(jnp.float32)),
+                 pad(batch.spinup_delay.astype(jnp.float32)[:, None]),
+                 pad(batch.task_prio.astype(jnp.float32)))
+        if control:
+            lanes = lanes + _control_lane_data(batch, pad,
+                                               *_control_derived(batch))
+        elif trace:
+            # vm_valid joins the lane data (and the gather) — positionally
+            # the next mr_epoch arg after prio
+            lanes = lanes + (pad(batch.vm_valid.astype(jnp.int32)),)
+        cur_state = initial_state(lanes[0], pad(ready0.astype(jnp.float32)),
+                                  lanes[2], lanes[3],
+                                  vm_start=lanes[8], vm_stop=lanes[9],
+                                  vm_auto=lanes[15] if control else None,
+                                  trace_capacity=(timeseries_capacity(
+                                      T, V, control) if trace else None))
+        # ``store`` is None until the first compaction (before that,
+        # ``cur_state`` IS the dense store in original lane order) — the
+        # engine lean loop's store-merge invariant, which is what makes
+        # donating ``cur_state`` into each chunk safe: no N-sized alias of
+        # the donated carry ever exists on the host side.  The freshness
+        # flags guard the other aliasing hazard: ``initial_state``
+        # forwards some lane arrays as state leaves unchanged (state[1]
+        # IS task_len), and donating a buffer that also rides in the same
+        # call's lane operands is an XLA error — so only carries/stores
+        # produced by a compute op inside this loop are ever donated.
+        store = None
+        state_fresh = store_fresh = False
+        cur_idx = np.arange(N + n_pad)
+        cur_lanes = lanes
+        n_act_dev, order_dev = _state_activity(
+            cur_lanes[3], cur_state[4], cur_state[12] if control else None)
+        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
+            n_act = int(pull(n_act_dev, stats))
+        stats["scalar_syncs"] += 1
     total = 0
     while total < bound:
         if n_act == 0:
@@ -413,42 +427,51 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
             # finished lanes, which step idempotently — the
             # device-computed order crosses the host boundary here and
             # only here
-            order = np.asarray(order_dev)[:pad_n]
-            stats["syncs"] += 1
-            if store is None:
-                store, store_fresh = cur_state, state_fresh
-            else:
-                store = (_put_lanes_donated if donate and store_fresh
-                         else _put_lanes)(store, jnp.asarray(cur_idx),
-                                          cur_state)
-                store_fresh = True
-            cur_idx = cur_idx[order]
-            take = jnp.asarray(cur_idx)
-            cur_lanes = _take_lanes(lanes, take)
-            cur_state = _take_lanes(store, take)
-            state_fresh = True
-            stats["compactions"] += 1
+            with jax.profiler.TraceAnnotation("iotsim.compact.regather"):
+                order = pull(order_dev, stats)[:pad_n]
+                stats["syncs"] += 1
+                if store is None:
+                    store, store_fresh = cur_state, state_fresh
+                else:
+                    store = (_put_lanes_donated if donate and store_fresh
+                             else _put_lanes)(store, put(cur_idx, stats),
+                                              cur_state)
+                    store_fresh = True
+                cur_idx = cur_idx[order]
+                take = put(cur_idx, stats)
+                cur_lanes = _take_lanes(lanes, take)
+                cur_state = _take_lanes(store, take)
+                state_fresh = True
+                stats["compactions"] += 1
         limit = min(k, bound - total)
         stats["dispatches"] += 1
+        stats["lane_epochs_allotted"] += len(cur_idx) * limit
         step = mr_epoch_donated if donate and state_fresh else mr_epoch
-        cur_state = step(*cur_lanes[:2], None, *cur_lanes[2:],
-                         state=cur_state, tile=tile, max_pes=max_pes,
-                         interpret=interpret, epoch_limit=limit,
-                         control=control, trace=trace,
-                         block_lanes=block_lanes)
-        state_fresh = True
-        total += limit
-        n_act_dev, order_dev = _state_activity(
-            cur_lanes[3], cur_state[4], cur_state[12] if control else None)
-        n_act = int(n_act_dev)
+        with jax.profiler.TraceAnnotation("iotsim.compact.step",
+                                          lanes=len(cur_idx),
+                                          epoch_limit=limit):
+            cur_state = step(*cur_lanes[:2], None, *cur_lanes[2:],
+                             state=cur_state, tile=tile, max_pes=max_pes,
+                             interpret=interpret, epoch_limit=limit,
+                             control=control, trace=trace,
+                             block_lanes=block_lanes)
+            state_fresh = True
+            total += limit
+            n_act_dev, order_dev = _state_activity(
+                cur_lanes[3], cur_state[4],
+                cur_state[12] if control else None)
+        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
+            n_act = int(pull(n_act_dev, stats))
         stats["scalar_syncs"] += 1
-    if store is None:
-        store = cur_state
-    else:
-        store = (_put_lanes_donated if donate and store_fresh
-                 else _put_lanes)(store, jnp.asarray(cur_idx), cur_state)
-    out = _sim_output_of_state(batch, store, N, control=control)
-    if trace:
-        C = store[-1].shape[1] // 8
-        return out, jnp.max(out.n_epochs), store[-1][:N].reshape(N, C, 8)
-    return out, jnp.max(out.n_epochs)
+    with jax.profiler.TraceAnnotation("iotsim.compact.finish"):
+        if store is None:
+            store = cur_state
+        else:
+            store = (_put_lanes_donated if donate and store_fresh
+                     else _put_lanes)(store, put(cur_idx, stats), cur_state)
+        out = _sim_output_of_state(batch, store, N, control=control)
+        if trace:
+            C = store[-1].shape[1] // 8
+            return (out, jnp.max(out.n_epochs),
+                    store[-1][:N].reshape(N, C, 8))
+        return out, jnp.max(out.n_epochs)

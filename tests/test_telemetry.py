@@ -372,3 +372,158 @@ def test_sweep_parquet_provenance(tmp_path):
     assert prov["repro_version"] and prov["jax_version"]
     assert streamed.n_rows == 3
     assert rep.n_cells == 3 and rep.dispatches >= 2   # >= one per chunk
+
+
+# ---------------------------------------------------------------------------
+# Host spans and transfer / lane-epoch counters of SweepPlan.run
+# ---------------------------------------------------------------------------
+
+# The span that each iotsim.* span sits directly inside (DESIGN.md §12.4);
+# the first activity poll of a compaction sits in its prepare span.
+_SPAN_PARENTS = {
+    "iotsim.run": {None},
+    "iotsim.plan": {"iotsim.run"},
+    "iotsim.assemble": {"iotsim.run"},
+    "iotsim.bucket": {"iotsim.run"},
+    "iotsim.upload": {"iotsim.bucket"},
+    "iotsim.launch": {"iotsim.bucket"},
+    "iotsim.readback": {"iotsim.bucket"},
+    "iotsim.metrics": {"iotsim.bucket"},
+    "iotsim.compact.prepare": {"iotsim.bucket"},
+    "iotsim.compact.step": {"iotsim.bucket"},
+    "iotsim.compact.regather": {"iotsim.bucket"},
+    "iotsim.compact.finish": {"iotsim.bucket"},
+    "iotsim.compact.poll": {"iotsim.bucket", "iotsim.compact.prepare"},
+}
+_DENSE = {"iotsim.run", "iotsim.plan", "iotsim.assemble", "iotsim.bucket",
+          "iotsim.upload", "iotsim.launch", "iotsim.readback"}
+_COMPACT = (_DENSE - {"iotsim.launch"}) | {
+    "iotsim.metrics", "iotsim.compact.prepare", "iotsim.compact.step",
+    "iotsim.compact.poll", "iotsim.compact.regather",
+    "iotsim.compact.finish"}
+
+
+def _tail_plan():
+    """16 cells, 1 to 24 maps on 1 to 8 VMs: most lanes finish long
+    before the last, so a compacting run regathers."""
+    return product(axis("n_maps", [1, 2, 3, 24]), axis("n_vms", [1, 2, 4, 8]))
+
+
+def _traced_run(tmp_path, plan, **kw):
+    """``plan.run(report=True, **kw)`` under the profiler, after a plain
+    run (which also compiles outside the trace) whose results it must
+    match bit for bit; returns the result, the report and the run's
+    ``iotsim.*`` spans (:func:`_iotsim_spans`)."""
+    base = plan.run(cost_model=_PINNED, **kw)
+    with jax.profiler.trace(str(tmp_path)):
+        res, rep = plan.run(cost_model=_PINNED, report=True, **kw)
+    for name in base.metric_names:
+        np.testing.assert_array_equal(base[name], res[name],
+                                      err_msg=f"{name} ({kw})")
+    return res, rep, _iotsim_spans(tmp_path)
+
+
+def _iotsim_spans(trace_dir):
+    """``(start, end, name, attributes)`` of the ``iotsim.*`` host spans in
+    the one profiler session under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = trace_dir.glob("**/*.xplane.pb")
+    return [(e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines if line.name.startswith("python")
+            for e in line.events if e.name.startswith("iotsim.")]
+
+
+def _parent(span, spans):
+    """Name of the innermost other span that holds ``span``."""
+    s, e = span[:2]
+    holders = [o for o in spans if o is not span and o[0] <= s
+               and e <= o[1] and (o[1] - o[0]) > (e - s)]
+    return min(holders, key=lambda o: o[1] - o[0])[2] if holders else None
+
+
+# Also pins that the profiler and report=True leave every result bit as
+# it is (_traced_run).
+@pytest.mark.parametrize("kw, names", [
+    (dict(), _DENSE),
+    (dict(compact=2), _COMPACT),
+    (dict(backend="pallas", compact=2), _COMPACT),
+    (dict(bucket=False, compact=2), _COMPACT),
+], ids=["dense", "xla-compact", "pallas-compact", "one-bucket-compact"])
+def test_run_spans_nest_and_match_counters(tmp_path, kw, names):
+    plan = _tail_plan()
+    res, rep, spans = _traced_run(tmp_path, plan, **kw)
+    count = {n: sum(s[2] == n for s in spans) for n in _SPAN_PARENTS}
+    assert {n for n, c in count.items() if c} == names
+    for span in spans:
+        assert _parent(span, spans) in _SPAN_PARENTS[span[2]], span
+    (run,) = [s for s in spans if s[2] == "iotsim.run"]
+    # the profiler hands back attribute values as it parses them
+    assert {k: str(v) for k, v in run[3].items()} == {
+        "run": str(run[3]["run"]), "cells": str(plan.size),
+        "backend": kw.get("backend", "xla"),
+        "compact": str(kw.get("compact"))}
+    buckets = [s for s in spans if s[2] == "iotsim.bucket"]
+    assert [(b[3]["bucket"], b[3]["cells"], b[3]["pad_tasks"],
+             b[3]["pad_vms"], b[3]["run"]) for b in buckets] == [
+        (i, b.cells, b.pad_tasks, b.pad_vms, run[3]["run"])
+        for i, b in enumerate(rep.buckets)]
+    if "compact" in kw:
+        assert count["iotsim.compact.poll"] == rep.scalar_syncs
+        assert count["iotsim.compact.step"] == rep.dispatches
+        assert count["iotsim.compact.regather"] == rep.compaction_syncs > 0
+    else:
+        assert count["iotsim.launch"] == rep.dispatches == rep.n_buckets
+    assert rep.d2h_transfers >= rep.compaction_syncs + rep.scalar_syncs
+    assert rep.h2d_transfers > 0 and rep.h2d_bytes > 0
+    assert rep.d2h_bytes > 0
+
+
+@pytest.mark.parametrize("kw", [
+    dict(), dict(bucket=False), dict(compact=2),
+    dict(backend="pallas", compact=2), dict(backend="pallas"),
+    dict(chunk=12), dict(chunk=12, compact=2)],
+    ids=["dense", "one-bucket", "xla-compact", "pallas-compact",
+         "pallas-dense", "chunk", "chunk-compact"])
+def test_run_lane_epoch_counters(kw):
+    plan = _tail_plan()
+    res, rep = plan.run(cost_model=_PINNED, report=True, **kw)
+    n_epochs = res["n_epochs"]
+    assert rep.lane_epochs_useful == int(n_epochs.sum())
+    assert rep.lane_epochs_useful == sum(b.lane_epochs_useful
+                                         for b in rep.buckets)
+    assert rep.lane_epochs_allotted >= rep.lane_epochs_useful
+    assert rep.lane_epochs_allotted == sum(b.lane_epochs_allotted
+                                           for b in rep.buckets)
+    if "compact" not in kw and "chunk" not in kw:
+        # a dense bucket allots its padded lanes x its realized epochs
+        from repro.kernels.mr_sched import ops
+        _, tile = ops.resolve_mode(None, None)
+        realized = set(res["realized_epochs"].ravel().tolist())
+        for b in rep.buckets:
+            lanes = b.cells
+            if kw.get("backend") == "pallas":
+                lanes += ops.lane_pad(b.cells, tile)
+            assert b.lane_epochs_allotted in {lanes * r for r in realized}
+
+
+@pytest.mark.parametrize("legacy", [False, True], ids=["lean", "legacy"])
+def test_compact_loop_spans_match_stats(tmp_path, legacy):
+    """Both XLA compaction loops: one poll span per scalar pull, one step
+    span per chunk launch, one regather span per full pull."""
+    batch = sweep.grid_arrays(_tail_plan().params(), pad_tasks=25, pad_vms=8)
+    engine.simulate_batch_arrays_compact(batch, k=2, legacy=legacy)
+    st = {}
+    with jax.profiler.trace(str(tmp_path)):
+        engine.simulate_batch_arrays_compact(batch, k=2, legacy=legacy,
+                                             stats=st)
+    names = [s[2] for s in _iotsim_spans(tmp_path)]
+    assert names.count("iotsim.compact.prepare") == 1
+    assert names.count("iotsim.compact.finish") == 1
+    assert names.count("iotsim.compact.poll") == st["scalar_syncs"]
+    assert names.count("iotsim.compact.step") == st["dispatches"]
+    assert names.count("iotsim.compact.regather") == st["syncs"]
+    assert st["compactions"] > 0
+    assert st["d2h_transfers"] >= st["syncs"] + st["scalar_syncs"]
+    assert st["lane_epochs_allotted"] > 0
