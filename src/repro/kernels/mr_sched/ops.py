@@ -13,6 +13,8 @@ event-loop hot path runs in a Pallas kernel:
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -271,6 +273,63 @@ def _state_activity(valid, finish, shed):
     return jnp.sum(act, dtype=jnp.int32), jnp.argsort(~act)
 
 
+@partial(jax.jit, static_argnames=("n_pad", "control", "trace_capacity"))
+def _compact_prepare(batch: ScenarioArrays, *, n_pad: int, control: bool,
+                     trace_capacity: int | None):
+    """The Pallas compact driver's set-up, one program: derived inputs,
+    the padded lane tuple (``_control_lane_data`` under ``control``, the
+    ``vm_valid`` lane for an open-loop trace), the t=0 carry state and its
+    activity reduction.  Returns ``(lanes, state, n_act, order)``."""
+    task_len, ready0, shuffle = _derived_inputs(batch)
+
+    def pad(x):     # pad lanes hold no valid tasks -> inactive from t=0
+        widths = ((0, n_pad),) + ((0, 0),) * (x.ndim - 1)
+        return jnp.pad(x, widths)
+
+    lanes = (pad(task_len.astype(jnp.float32)),
+             pad(batch.task_vm.astype(jnp.int32)),
+             pad(batch.task_is_reduce.astype(jnp.int32)),
+             pad(batch.task_valid.astype(jnp.int32)),
+             pad(shuffle.astype(jnp.float32)[:, None]),
+             pad(batch.vm_mips.astype(jnp.float32)),
+             pad(batch.vm_pes.astype(jnp.float32)),
+             pad(batch.sched_policy.astype(jnp.int32)[:, None]),
+             pad(batch.vm_start.astype(jnp.float32)),
+             pad(batch.vm_stop.astype(jnp.float32)),
+             pad(batch.spinup_delay.astype(jnp.float32)[:, None]),
+             pad(batch.task_prio.astype(jnp.float32)))
+    if control:
+        lanes = lanes + _control_lane_data(batch, pad,
+                                           *_control_derived(batch))
+    elif trace_capacity is not None:
+        # vm_valid joins the lane data (and the gather) — positionally
+        # the next mr_epoch arg after prio
+        lanes = lanes + (pad(batch.vm_valid.astype(jnp.int32)),)
+    state = initial_state(lanes[0], pad(ready0.astype(jnp.float32)),
+                          lanes[2], lanes[3],
+                          vm_start=lanes[8], vm_stop=lanes[9],
+                          vm_auto=lanes[15] if control else None,
+                          trace_capacity=trace_capacity)
+    return (lanes, state,
+            *_state_activity(lanes[3], state[4],
+                             state[12] if control else None))
+
+
+@partial(jax.jit, static_argnames=("control", "trace"))
+def _compact_finish(batch: ScenarioArrays, store, *, control: bool,
+                    trace: bool):
+    """The Pallas compact driver's output, one program: the merged
+    (padded) store as a :class:`SimOutput`, its realized epoch count and,
+    under ``trace``, the time-series rows ``(N, C, 8)``."""
+    N = batch.task_vm.shape[0]
+    out = _sim_output_of_state(batch, store, N, control=control)
+    if trace:
+        C = store[-1].shape[1] // 8
+        return (out, jnp.max(out.n_epochs),
+                store[-1][:N].reshape(N, C, 8))
+    return out, jnp.max(out.n_epochs)
+
+
 def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
                            tile: int | None = None,
                            max_pes: int | None = None,
@@ -327,7 +386,15 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
     (``mr_epoch_donated``) and the donating store-scatter, so the carry
     updates in place instead of copying every chunk (the engine lean
     loop's store-merge invariant, see
-    ``engine._compact_loop_lean``).
+    ``engine._compact_loop_lean``).  The t=0 state is never donated:
+    ``initial_state`` forwards lane arrays as state leaves.
+
+    The set-up and output glue run as one compiled program each,
+    :func:`_compact_prepare` and :func:`_compact_finish`, whose shapes
+    are fixed by the batch.  Only the final store merge (``_put_lanes``)
+    stays a call of its own: its index length is the last round's pow2
+    pad, which varies from call to call, and would otherwise multiply
+    the output program's compile variants.
     """
     if stats is None:
         stats = {}
@@ -366,38 +433,11 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
     if k < 1:
         raise ValueError(f"epoch_schedule_compact: k must be >= 1, got {k}")
     with jax.profiler.TraceAnnotation("iotsim.compact.prepare"):
-        task_len, ready0, shuffle = _derived_inputs(batch)
         n_pad = lane_pad(N, tile)
-
-        def pad(x):     # pad lanes hold no valid tasks -> inactive from t=0
-            widths = ((0, n_pad),) + ((0, 0),) * (x.ndim - 1)
-            return jnp.pad(x, widths)
-
-        lanes = (pad(task_len.astype(jnp.float32)),
-                 pad(batch.task_vm.astype(jnp.int32)),
-                 pad(batch.task_is_reduce.astype(jnp.int32)),
-                 pad(batch.task_valid.astype(jnp.int32)),
-                 pad(shuffle.astype(jnp.float32)[:, None]),
-                 pad(batch.vm_mips.astype(jnp.float32)),
-                 pad(batch.vm_pes.astype(jnp.float32)),
-                 pad(batch.sched_policy.astype(jnp.int32)[:, None]),
-                 pad(batch.vm_start.astype(jnp.float32)),
-                 pad(batch.vm_stop.astype(jnp.float32)),
-                 pad(batch.spinup_delay.astype(jnp.float32)[:, None]),
-                 pad(batch.task_prio.astype(jnp.float32)))
-        if control:
-            lanes = lanes + _control_lane_data(batch, pad,
-                                               *_control_derived(batch))
-        elif trace:
-            # vm_valid joins the lane data (and the gather) — positionally
-            # the next mr_epoch arg after prio
-            lanes = lanes + (pad(batch.vm_valid.astype(jnp.int32)),)
-        cur_state = initial_state(lanes[0], pad(ready0.astype(jnp.float32)),
-                                  lanes[2], lanes[3],
-                                  vm_start=lanes[8], vm_stop=lanes[9],
-                                  vm_auto=lanes[15] if control else None,
-                                  trace_capacity=(timeseries_capacity(
-                                      T, V, control) if trace else None))
+        lanes, cur_state, n_act_dev, order_dev = _compact_prepare(
+            batch, n_pad=n_pad, control=control,
+            trace_capacity=(timeseries_capacity(T, V, control) if trace
+                            else None))
         # ``store`` is None until the first compaction (before that,
         # ``cur_state`` IS the dense store in original lane order) — the
         # engine lean loop's store-merge invariant, which is what makes
@@ -405,15 +445,15 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
         # the donated carry ever exists on the host side.  The freshness
         # flags guard the other aliasing hazard: ``initial_state``
         # forwards some lane arrays as state leaves unchanged (state[1]
-        # IS task_len), and donating a buffer that also rides in the same
-        # call's lane operands is an XLA error — so only carries/stores
-        # produced by a compute op inside this loop are ever donated.
+        # IS task_len), so the t=0 state out of ``_compact_prepare`` may
+        # share buffers with the lane operands, and donating a buffer
+        # that also rides in the same call's lane operands is an XLA
+        # error — so only carries/stores produced by a compute op inside
+        # this loop are ever donated.
         store = None
         state_fresh = store_fresh = False
         cur_idx = np.arange(N + n_pad)
         cur_lanes = lanes
-        n_act_dev, order_dev = _state_activity(
-            cur_lanes[3], cur_state[4], cur_state[12] if control else None)
         with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
             n_act = int(pull(n_act_dev, stats))
         stats["scalar_syncs"] += 1
@@ -469,9 +509,4 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
         else:
             store = (_put_lanes_donated if donate and store_fresh
                      else _put_lanes)(store, put(cur_idx, stats), cur_state)
-        out = _sim_output_of_state(batch, store, N, control=control)
-        if trace:
-            C = store[-1].shape[1] // 8
-            return (out, jnp.max(out.n_epochs),
-                    store[-1][:N].reshape(N, C, 8))
-        return out, jnp.max(out.n_epochs)
+        return _compact_finish(batch, store, control=control, trace=trace)

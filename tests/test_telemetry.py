@@ -423,16 +423,23 @@ def _traced_run(tmp_path, plan, **kw):
     return res, rep, _iotsim_spans(tmp_path)
 
 
-def _iotsim_spans(trace_dir):
-    """``(start, end, name, attributes)`` of the ``iotsim.*`` host spans in
-    the one profiler session under ``trace_dir``."""
+def _host_events(trace_dir, prefix=""):
+    """``(start, end, name, attributes)`` of the Python thread's host
+    events whose names start with ``prefix``, in the one profiler session
+    under ``trace_dir``."""
     from jax.profiler import ProfileData
     (path,) = trace_dir.glob("**/*.xplane.pb")
     return [(e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))
             for plane in ProfileData.from_file(str(path)).planes
             if plane.name.startswith("/host:CPU")
             for line in plane.lines if line.name.startswith("python")
-            for e in line.events if e.name.startswith("iotsim.")]
+            for e in line.events if e.name.startswith(prefix)]
+
+
+def _iotsim_spans(trace_dir):
+    """``(start, end, name, attributes)`` of the ``iotsim.*`` host spans in
+    the one profiler session under ``trace_dir``."""
+    return _host_events(trace_dir, "iotsim.")
 
 
 def _parent(span, spans):
@@ -527,3 +534,45 @@ def test_compact_loop_spans_match_stats(tmp_path, legacy):
     assert st["compactions"] > 0
     assert st["d2h_transfers"] >= st["syncs"] + st["scalar_syncs"]
     assert st["lane_epochs_allotted"] > 0
+
+
+_GLUE_SPANS = ("iotsim.compact.prepare", "iotsim.compact.finish")
+
+
+@pytest.mark.parametrize("kw", [dict(), dict(control=True), dict(trace=True)],
+                         ids=["open-loop", "control", "trace"])
+def test_pallas_compact_glue_programs(tmp_path, kw):
+    """The Pallas compaction driver's set-up and output glue run as one
+    compiled program each: at most two executable launches inside
+    ``prepare`` (glue and activity, here fused into one) and two inside
+    ``finish`` (the store merge and the output), not one per ``jnp`` op."""
+    batch = sweep.grid_arrays(_tail_plan().params(), pad_tasks=25, pad_vms=8)
+    epoch_schedule_compact(batch, k=2, tile=8, interpret=True, **kw)
+    with jax.profiler.trace(str(tmp_path)):
+        epoch_schedule_compact(batch, k=2, tile=8, interpret=True, **kw)
+    events = _host_events(tmp_path)
+    runs = [e for e in events if e[2] == "PjRtCpuExecutable::Execute"]
+    for name in _GLUE_SPANS:
+        (span,) = [e for e in events if e[2] == name]
+        n = sum(span[0] <= r[0] and r[1] <= span[1] for r in runs)
+        assert 1 <= n <= 2, (name, n)
+
+
+def test_pallas_compact_glue_compiles_once():
+    """Another round count on a batch of the same shapes reuses the glue
+    programs: what a benchmark window runs after warm-up compiles none."""
+    from repro.kernels.mr_sched import ops
+    shapes = dict(pad_tasks=25, pad_vms=8)
+    batch = sweep.grid_arrays(_tail_plan().params(), **shapes)
+    other = sweep.grid_arrays(product(axis("n_maps", [2, 5, 7, 20]),
+                                      axis("n_vms", [1, 3, 6, 8])).params(),
+                              **shapes)
+    first, _ = epoch_schedule_compact(batch, k=2, tile=8, interpret=True)
+    sizes = (ops._compact_prepare._cache_size(),
+             ops._compact_finish._cache_size())
+    again, _ = epoch_schedule_compact(batch, k=3, tile=8, interpret=True)
+    epoch_schedule_compact(other, k=5, tile=8, interpret=True)
+    assert (ops._compact_prepare._cache_size(),
+            ops._compact_finish._cache_size()) == sizes
+    for a, b in zip(jax.tree.leaves(first), jax.tree.leaves(again)):
+        np.testing.assert_array_equal(a, b)
