@@ -42,6 +42,7 @@ import dataclasses
 import enum
 import inspect
 import itertools
+import math
 import time
 from functools import lru_cache, partial
 from typing import Any, Mapping, Sequence
@@ -49,6 +50,7 @@ from typing import Any, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from . import costmodel as costmodel_mod
 from . import elasticity as elasticity_mod
@@ -400,6 +402,112 @@ def _validate_cell_columns(cols: Mapping[str, Any]) -> None:
             "explicit storage_enabled column)")
 
 
+# ---------------------------------------------------------------------------
+# One buffer each way per launch (DESIGN.md §4.3)
+# ---------------------------------------------------------------------------
+
+def _word_type(dtype) -> np.dtype:
+    """The 4-byte type a device dtype crosses as: the dtype itself, or for
+    a narrower one (``bool``, ``int8``, ``float16``, …) the 32-bit type of
+    its kind, which holds every value exactly."""
+    dtype = np.dtype(dtype)
+    if dtype.itemsize == 4:
+        return dtype
+    if dtype.itemsize > 4:
+        raise TypeError(f"no 32-bit word layout for dtype {dtype}")
+    if dtype == np.bool_ or dtype.kind == "u":
+        return np.dtype(np.uint32)
+    return np.dtype(np.int32 if dtype.kind == "i" else np.float32)
+
+
+def _upload_layout(cols: Mapping[str, Any], names: Sequence[str]
+                   ) -> tuple[tuple[str, np.dtype, tuple[int, ...]], ...]:
+    """``(name, device dtype, per-cell shape)`` of each host column, in
+    buffer order.  The dtype is the one ``jnp.asarray`` gives the column
+    (float64 -> float32, int64 -> int32).  A pure function of the names,
+    dtypes and widths, so it keys the signature caches."""
+    return tuple((n, jax.dtypes.canonicalize_dtype(np.asarray(cols[n]).dtype),
+                  tuple(np.shape(cols[n])[1:])) for n in names)
+
+
+def _pack_columns(cols: Mapping[str, Any], layout) -> np.ndarray:
+    """A launch's host columns as one contiguous uint32 buffer, column
+    after column, each cast exactly as ``jnp.asarray`` casts it."""
+    n = len(cols[layout[0][0]])
+    sizes = [n * math.prod(shape) for _, _, shape in layout]
+    buf = np.empty(sum(sizes), np.uint32)
+    off = 0
+    for (name, dtype, shape), size in zip(layout, sizes):
+        words = buf[off:off + size].view(_word_type(dtype))
+        words.reshape((n,) + shape)[...] = np.asarray(cols[name], dtype)
+        off += size
+    return buf
+
+
+def _unpack_columns(buf, layout) -> list:
+    """Inverse of :func:`_pack_columns` inside the program: static slices,
+    bitcasts and reshapes give back every column bit for bit."""
+    if not layout:
+        return []
+    n = buf.shape[0] // sum(math.prod(shape) for _, _, shape in layout)
+    cols, off = [], 0
+    for _, dtype, shape in layout:
+        size = n * math.prod(shape)
+        words = lax.slice(buf, (off,), (off + size,))
+        x = lax.bitcast_convert_type(words, _word_type(dtype))
+        cols.append(x.reshape((n,) + shape).astype(dtype))
+        off += size
+    return cols
+
+
+def _avals(args) -> tuple:
+    """Shapes and dtypes of a call's array arguments: the key of the
+    readback layout that tracing the call recorded."""
+    return tuple((tuple(x.shape), np.dtype(x.dtype))
+                 for x in jax.tree.leaves(args))
+
+
+class _OneReadback:
+    """``jax.jit(fn)`` whose pytree of results leaves the device as one
+    flat uint32 buffer, so reading a launch back is one pull.  Tracing
+    records the layout (tree structure, leaf shapes and dtypes) under the
+    arguments' shapes and dtypes; :meth:`pull` slices the host copy of the
+    buffer with it."""
+
+    def __init__(self, fn):
+        layouts: dict = {}
+
+        def packed(*args):
+            leaves, tree = jax.tree.flatten(fn(*args))
+            layouts[_avals(args)] = tree, tuple(
+                (tuple(x.shape), np.dtype(x.dtype)) for x in leaves)
+            return jnp.concatenate([
+                lax.bitcast_convert_type(x.astype(_word_type(x.dtype)),
+                                         jnp.uint32).reshape(-1)
+                for x in leaves])
+
+        self._layouts = layouts
+        self.jit = jax.jit(packed)
+
+    def __call__(self, *args):
+        """Launch; returns ``(device buffer, layout key)`` for :meth:`pull`."""
+        return self.jit(*args), _avals(args)
+
+    def pull(self, launched, stats: dict | None):
+        """One blocking pull of a launch's buffer; its results on the host
+        as views of that one array."""
+        buf, key = launched
+        tree, leaves = self._layouts[key]
+        host = telemetry.pull(buf, stats)
+        out, off = [], 0
+        for shape, dtype in leaves:
+            size = math.prod(shape)
+            x = host[off:off + size].view(_word_type(dtype)).reshape(shape)
+            out.append(x.astype(dtype, copy=False))
+            off += size
+        return tree.unflatten(out)
+
+
 def grid_arrays(params: dict[str, np.ndarray], *, pad_tasks: int,
                 pad_vms: int,
                 static_params: Mapping[str, int] | None = None,
@@ -419,7 +527,9 @@ def grid_arrays(params: dict[str, np.ndarray], *, pad_tasks: int,
     strategies (the sequential LEAST_LOADED load scan dominates encode
     time when it can't be eliminated).
 
-    ``stats`` (a dict, mutated in place) counts the columns uploaded
+    Host columns cross in one uploaded buffer (:func:`_pack_columns`);
+    ``jax.Array`` columns are already on the device and pass through as
+    they are.  ``stats`` (a dict, mutated in place) counts the upload
     (:func:`telemetry.put`).
     """
     names = list(params)
@@ -470,24 +580,34 @@ def grid_arrays(params: dict[str, np.ndarray], *, pad_tasks: int,
             "grid_arrays: parameter arrays must share one leading grid "
             f"length; {names[0]!r} has length {n0} but " + ", ".join(bad))
     _validate_cell_columns(params)
-    encoder = _grid_encoder(tuple(names), pad_tasks, pad_vms, static)
+    on_device = tuple(n for n in names if isinstance(params[n], jax.Array))
+    layout = _upload_layout(params, [n for n in names if n not in on_device])
+    encoder = _grid_encoder(layout, on_device, pad_tasks, pad_vms, static)
     with jax.profiler.TraceAnnotation("iotsim.upload"):
-        args = [telemetry.put(params[n], stats) for n in names]
-    return encoder(*args)
+        buf = (telemetry.put(_pack_columns(params, layout), stats)
+               if layout else None)
+    return encoder(buf, *(params[n] for n in on_device))
 
 
 @lru_cache(maxsize=None)
-def _grid_encoder(names: tuple[str, ...], pad_tasks: int, pad_vms: int,
-                  static: tuple[tuple[str, int], ...] = ()):
-    """One jitted vmapped encode_cell per (param set, padding, statics)
-    signature — repeated ``SweepPlan.run()`` calls re-encode at compiled
-    speed instead of dispatching the encoder op by op."""
+def _grid_encoder(layout: tuple, on_device: tuple[str, ...], pad_tasks: int,
+                  pad_vms: int, static: tuple[tuple[str, int], ...] = ()):
+    """One jitted vmapped encode_cell per (column layout, device columns,
+    padding, statics) signature — repeated ``SweepPlan.run()`` calls
+    re-encode at compiled speed instead of dispatching the encoder op by
+    op.  It takes the packed host columns and the device columns."""
+    names = tuple(n for n, _, _ in layout) + on_device
+
     def one(*xs):
         kw = dict(zip(names, xs))
         kw.update(static)
         with jax.named_scope("encode"):
             return encode_cell(**kw, pad_tasks=pad_tasks, pad_vms=pad_vms)
-    return jax.jit(jax.vmap(one))
+
+    @jax.jit
+    def encode(buf, *device_cols):
+        return jax.vmap(one)(*_unpack_columns(buf, layout), *device_cols)
+    return encode
 
 
 # ---------------------------------------------------------------------------
@@ -1339,7 +1459,7 @@ def _bucket_groups(cols: dict[str, np.ndarray], pad_tasks: int, pad_vms: int,
 
 
 @lru_cache(maxsize=None)
-def _fused_runner(names: tuple[str, ...], pad_tasks: int, pad_vms: int,
+def _fused_runner(layout: tuple, pad_tasks: int, pad_vms: int,
                   statics: tuple[tuple[str, int], ...], backend: str,
                   max_pes: int = 0, control: bool = False):
     """encode + simulate + metrics as ONE jitted callable per bucket
@@ -1347,17 +1467,21 @@ def _fused_runner(names: tuple[str, ...], pad_tasks: int, pad_vms: int,
     cost is dominated by per-call overhead on small hosts), and — the key
     effect — ``statics`` and encode_cell's scalar defaults become trace
     constants *inside the engine*, so XLA folds the per-bucket policy
-    branches instead of carrying both policies' machinery at runtime."""
+    branches instead of carrying both policies' machinery at runtime.
+    The columns arrive as one buffer in ``layout`` (:func:`_pack_columns`)
+    and the results and realized epoch count leave as one
+    (:class:`_OneReadback`)."""
+    names = tuple(n for n, _, _ in layout)
     static_kw = dict(statics)
 
-    def run(*xs):
+    def run(buf):
         def one(*cell):
             kw = dict(zip(names, cell))
             kw.update(static_kw)
             return encode_cell(**kw, pad_tasks=pad_tasks, pad_vms=pad_vms)
 
         with jax.named_scope("encode"):
-            batch = jax.vmap(one)(*xs)
+            batch = jax.vmap(one)(*_unpack_columns(buf, layout))
         with jax.named_scope("epoch_loop"):
             if backend == "pallas":
                 from repro.kernels.mr_sched import \
@@ -1368,7 +1492,7 @@ def _fused_runner(names: tuple[str, ...], pad_tasks: int, pad_vms: int,
                 out, realized = simulate_batch_arrays(batch, control=control)
         return _metrics_of(batch, out) + (realized,)
 
-    return jax.jit(run)
+    return _OneReadback(run)
 
 
 def _metrics_of(batch, out):
@@ -1379,11 +1503,14 @@ def _metrics_of(batch, out):
                 jax.vmap(scenario_metrics)(batch, out))
 
 
-@jax.jit
-def _metrics_batch(batch, out):
+def _metrics_and_realized(batch, out, realized):
     """Fused metrics pass for the compacted path (its epoch stepping is
-    host-driven, so metrics dispatch separately from simulation)."""
-    return _metrics_of(batch, out)
+    host-driven, so metrics dispatch separately from simulation); the
+    realized epoch count rides in its one readback buffer."""
+    return _metrics_of(batch, out) + (realized,)
+
+
+_metrics_batch = _OneReadback(_metrics_and_realized)
 
 
 def _run_compact(cols: dict[str, np.ndarray], pad_tasks: int, pad_vms: int,
@@ -1395,7 +1522,9 @@ def _run_compact(cols: dict[str, np.ndarray], pad_tasks: int, pad_vms: int,
     metrics.  Encode and metrics stay fused and signature-cached exactly
     like the dense runner; only the epoch loop leaves jit, because
     compaction needs host control flow over the active-lane count (XLA
-    shapes are static)."""
+    shapes are static).  Returns host ``(JobMetrics, ScenarioMetrics,
+    realized)``: one column upload and one readback besides the loop's
+    own transfers."""
     batch = grid_arrays(cols, pad_tasks=pad_tasks, pad_vms=pad_vms,
                         static_params=statics, stats=stats)
     if backend == "pallas":
@@ -1410,9 +1539,10 @@ def _run_compact(cols: dict[str, np.ndarray], pad_tasks: int, pad_vms: int,
                                                       control=control,
                                                       stats=stats)
     with jax.profiler.TraceAnnotation("iotsim.metrics"):
-        jm, sm = _metrics_batch(batch, out)
+        launched = _metrics_batch(batch, out, realized)
     with jax.profiler.TraceAnnotation("iotsim.readback"):
-        return jm, sm, int(telemetry.pull(realized, stats))
+        jm, sm, realized = _metrics_batch.pull(launched, stats)
+    return jm, sm, int(realized)
 
 
 def _run_cells(cols: dict[str, np.ndarray], n: int, pad_tasks: int,
@@ -1424,16 +1554,13 @@ def _run_cells(cols: dict[str, np.ndarray], n: int, pad_tasks: int,
     ``(JobMetrics, ScenarioMetrics, realized_epochs[n])``.  ``stats``
     (a dict, mutated in place) counts device ``dispatches``, the compact
     drivers' host ``syncs``/``compactions``, the transfers
-    (:func:`telemetry.put`/:func:`telemetry.pull`) and
+    (:func:`telemetry.put`/:func:`telemetry.pull`: one column upload and
+    one result readback per launch off the mesh) and
     ``lane_epochs_allotted``."""
     if stats is None:
         stats = {}
     stats.setdefault("dispatches", 0)
     stats.setdefault("lane_epochs_allotted", 0)
-
-    def readback(tree):
-        return jax.tree.map(lambda x: telemetry.pull(x, stats), tree)
-
     # the control path is keyed on column *presence* (host-decidable even
     # for traced columns — engine._control_active is not, under trace):
     # a plan that never names a control parameter pays zero control cost
@@ -1450,7 +1577,8 @@ def _run_cells(cols: dict[str, np.ndarray], n: int, pad_tasks: int,
             jm, sm = _simulate_full_sharded(batch, mesh, control)
         stats["dispatches"] += 1
         with jax.profiler.TraceAnnotation("iotsim.readback"):
-            jm, sm = jax.tree.map(lambda x: x[:n], readback((jm, sm)))
+            jm, sm = jax.tree.map(lambda x: telemetry.pull(x, stats)[:n],
+                                  (jm, sm))
         realized = int(np.max(sm.n_epochs))
         stats["lane_epochs_allotted"] += full * realized
         return jm, sm, np.full(n, realized, np.int32)
@@ -1467,35 +1595,32 @@ def _run_cells(cols: dict[str, np.ndarray], n: int, pad_tasks: int,
                 jm, sm, rz = _run_compact(part, pad_tasks, pad_vms, statics,
                                           backend, compact, cost, max_pes,
                                           control, stats)
-                with jax.profiler.TraceAnnotation("iotsim.readback"):
-                    parts.append(jax.tree.map(lambda x: x[:take],
-                                              readback((jm, sm))))
+                parts.append(jax.tree.map(lambda x: x[:take], (jm, sm)))
                 realized[lo:lo + take] = rz
             jm, sm = jax.tree.map(lambda *xs: np.concatenate(xs), *parts)
             return jm, sm, realized
         jm, sm, rz = _run_compact(cols, pad_tasks, pad_vms, statics,
                                   backend, compact, cost, max_pes, control,
                                   stats)
-        with jax.profiler.TraceAnnotation("iotsim.readback"):
-            jm, sm = readback((jm, sm))
         return jm, sm, np.full(n, rz, np.int32)
-    names = tuple(sorted(cols))
-    runner = _fused_runner(names, pad_tasks, pad_vms,
+    layout = _upload_layout(cols, sorted(cols))
+    runner = _fused_runner(layout, pad_tasks, pad_vms,
                            tuple(sorted((statics or {}).items())),
                            backend, max_pes, control)
 
     def launch(part):
-        """Upload, run and read back one batch of cells; ``(jm, sm,
-        realized)`` on the host."""
+        """Upload, run and read back one batch of cells, one transfer each
+        way; ``(jm, sm, realized)`` on the host."""
         with jax.profiler.TraceAnnotation("iotsim.upload"):
-            args = [telemetry.put(part[k], stats) for k in names]
+            buf = telemetry.put(_pack_columns(part, layout), stats)
         with jax.profiler.TraceAnnotation("iotsim.launch"):
-            jm, sm, rz = runner(*args)
+            launched = runner(buf)
         stats["dispatches"] += 1
         with jax.profiler.TraceAnnotation("iotsim.readback"):
-            jm, sm = readback((jm, sm))
-            rz = int(telemetry.pull(rz, stats))
-        stats["lane_epochs_allotted"] += _lanes(len(args[0]), backend) * rz
+            jm, sm, rz = runner.pull(launched, stats)
+        rz = int(rz)
+        stats["lane_epochs_allotted"] += (
+            _lanes(len(part[layout[0][0]]), backend) * rz)
         return jm, sm, rz
 
     if chunk is not None:

@@ -118,17 +118,17 @@ def test_mr_epoch_rejects_unaligned_blocks():
 
 def test_fused_runner_compiles_mixed_bucket(one_chip):
     """The XLA bucket runner at one real mixed-policy bucket: 8,192 cells,
-    T=21, V=9, both policies as lane data."""
+    T=21, V=9, both policies as lane data, its columns in one uint32
+    buffer."""
     n = 8192
-    cols = {
-        "n_maps": (n,), "n_reduces": (n,), "n_vms": (n,), "vm_mips": (n,),
-        "vm_pes": (n,), "vm_cost": (n,), "job_length": (n,),
-        "job_data": (n,), "sched_policy": (n,), "binding_policy": (n,)}
-    names = tuple(sorted(cols))
-    args = [_spec(one_chip, cols[k],
-                  i32 if k in sweep._INT_PARAMS else f32) for k in names]
-    runner = sweep._fused_runner(names, T, V, (), "xla", 0, False)
-    compiled = runner.lower(*args).compile()
+    names = sorted(("n_maps", "n_reduces", "n_vms", "vm_mips", "vm_pes",
+                    "vm_cost", "job_length", "job_data", "sched_policy",
+                    "binding_policy"))
+    layout = tuple((k, np.dtype(i32 if k in sweep._INT_PARAMS else f32), ())
+                   for k in names)
+    runner = sweep._fused_runner(layout, T, V, (), "xla", 0, False)
+    buf = _spec(one_chip, (n * len(names),), jnp.uint32)
+    compiled = runner.jit.lower(buf).compile()
     mem = compiled.memory_analysis()
     # the bucket's temporaries fit a 16 GiB v5e chip many times over
     assert mem.temp_size_in_bytes < (1 << 30), mem
