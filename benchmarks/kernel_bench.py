@@ -79,13 +79,13 @@ def _mr_batch(m_range=range(1, 21)):
 def mr_sched_rows():
     import numpy as np
 
-    from repro.kernels.mr_sched import epoch_schedule, schedule
-    from repro.kernels.mr_sched.ref import schedule_ref
+    from repro.core import engine
+    from repro.kernels.mr_sched import epoch_schedule
     batch = _mr_batch()
-    us_k = _time(lambda b: schedule(b, tile=8)[1], batch)
+    engine_ref = jax.vmap(engine.simulate_arrays)
     us_e = _time(lambda b: epoch_schedule(b, tile=8).finish, batch)
-    us_r = _time(lambda b: schedule_ref(b)[1], batch)
-    s_r, f_r = schedule_ref(batch)
+    us_r = _time(lambda b: engine_ref(b).finish, batch)
+    f_r = engine_ref(batch).finish
     valid = np.asarray(batch.task_valid)
 
     def err(f_k):
@@ -93,8 +93,7 @@ def mr_sched_rows():
                                      np.asarray(f_k) - np.asarray(f_r),
                                      0)).max())
 
-    return [("kernel_mr_sched_interp", us_k, f"err={err(schedule(batch, tile=8)[1]):.1e}"),
-            ("kernel_mr_epoch_interp", us_e,
+    return [("kernel_mr_epoch_interp", us_e,
              f"err={err(epoch_schedule(batch, tile=8).finish):.1e}"),
             ("kernel_mr_sched_xla_engine_ref", us_r, "baseline")]
 

@@ -328,72 +328,6 @@ def tailheavy_rows(batch_sizes=(64, 2048), reps=7):
     return rows
 
 
-def compact_loop_rows(batch_sizes=(64, 2048), reps=7):
-    """The dispatch-lean compact loop vs the legacy per-round-sync loop
-    (DESIGN.md §13) at the *engine* level.
-
-    Both sides run :func:`engine.simulate_batch_arrays_compact` on the
-    tail-heavy batch at the same measured-cost interval K; the only
-    difference is the host loop: A (``legacy=True``) reproduces the
-    pre-lean driver — a full activity-mask device->host pull every round,
-    host-side argsort-free compaction order, no buffer donation — while B
-    is the lean loop — one fused 2-scalar pull per round, the on-device
-    active-first permutation materialized only on compacting rounds, and
-    carries/stores donated across the stepper and scatter calls.  Timed
-    min-of-alternating-A/B; the lean row's meta records
-    ``lean_speedup_vs_legacy`` (min-vs-min), both sides' sync/dispatch
-    census, and the cost coefficients that picked K."""
-    rows = []
-    cost = costmodel.default_cost_model()
-    for n in batch_sizes:
-        batch = _random_plan(n, np.random.default_rng(n),
-                             tailheavy=True).arrays()
-        k = cost.compact_interval(n, TAIL_PAD)
-        realized = [0]
-
-        def run_legacy(batch=batch, k=k):
-            out, _ = engine.simulate_batch_arrays_compact(batch, k=k,
-                                                          legacy=True)
-            jax.block_until_ready(out)
-
-        def run_lean(batch=batch, k=k, realized=realized):
-            out, rz = engine.simulate_batch_arrays_compact(batch, k=k)
-            jax.block_until_ready(out)
-            realized[0] = int(rz)
-
-        dt_a, min_a, dt_b, min_b = _time_ab(run_legacy, run_lean, reps)
-        st_legacy, st_lean = {}, {}
-        engine.simulate_batch_arrays_compact(batch, k=k, legacy=True,
-                                             stats=st_legacy)
-        engine.simulate_batch_arrays_compact(batch, k=k, stats=st_lean)
-        census = {"k": k,
-                  "legacy": {key: st_legacy[key] for key in
-                             ("dispatches", "syncs", "scalar_syncs",
-                              "compactions")},
-                  "lean": {key: st_lean[key] for key in
-                           ("dispatches", "syncs", "scalar_syncs",
-                            "compactions")}}
-        rows.append((f"sweep_throughput_compactloop_legacy_b{n}",
-                     dt_a * 1e6, min_a * 1e6, f"{n / dt_a:.0f}_scen/s",
-                     realized[0],
-                     {"k": k, "loop": "legacy_per_round_sync",
-                      "timing": "min_of_alternating_ab"}))
-        rows.append((f"sweep_throughput_compactloop_lean_b{n}",
-                     dt_b * 1e6, min_b * 1e6, f"{n / dt_b:.0f}_scen/s",
-                     realized[0],
-                     {"k": k, "loop": "lean_scalar_sync_donated",
-                      "donate": True,
-                      "timing": "min_of_alternating_ab",
-                      "lean_speedup_vs_legacy": round(min_a / min_b, 4),
-                      "census": census,
-                      "cost_model": {"dispatch_us": cost.dispatch_us,
-                                     "sync_us": cost.sync_us,
-                                     "epoch_lane_us": cost.epoch_lane_us,
-                                     "device": cost.device,
-                                     "source": cost.source}}))
-    return rows
-
-
 def control_rows(batch_sizes=(64, 2048), reps=7):
     """Closed-loop control vs the open-loop elastic grid (DESIGN.md §10).
 
@@ -579,7 +513,6 @@ def all_rows():
             + throughput_rows(batch_sizes=(64, 2048), locality=True)
             + throughput_rows(batch_sizes=(64, 2048), elastic=True)
             + tailheavy_rows()
-            + compact_loop_rows()
             + control_rows()
             + deadline_rows()
             + traced_rows())
@@ -602,9 +535,6 @@ def main() -> None:
     # compaction gap: noise-floor min vs min on the alternating-A/B pair
     th_dense = by_name["sweep_throughput_tailheavy_b2048"][2]
     th_comp = by_name["sweep_throughput_tailheavy_compact_b2048"][2]
-    # lean-loop gain: the engine-level legacy-vs-lean A/B pair (§13)
-    lean_speedup = by_name["sweep_throughput_compactloop_lean_b2048"][5][
-        "lean_speedup_vs_legacy"]
     # control gap: already min-vs-min from its own alternating-A/B pair
     ctl_gap = by_name["sweep_throughput_control_b2048"][5][
         "control_gap_vs_elastic"]
@@ -637,7 +567,6 @@ def main() -> None:
             "compaction_gap_vs_dense": round(th_comp / th_dense - 1.0, 4),
             "compaction_speedup_tailheavy_b2048": round(th_dense / th_comp,
                                                         2),
-            "compact_lean_speedup_vs_legacy_b2048": lean_speedup,
             "control_gap_vs_elastic": ctl_gap,
             "deadline_gap_vs_control": dl_gap,
             "trace_gap_vs_plain": tr_gap,
@@ -665,8 +594,6 @@ def main() -> None:
           f"{payload['meta']['elastic_gap_vs_mixedpol']:+.1%}")
     print(f"compaction vs dense tailheavy b2048 (min-of-A/B): "
           f"{payload['meta']['compaction_speedup_tailheavy_b2048']:.2f}x")
-    print(f"lean vs legacy compact loop b2048 (min-of-A/B): "
-          f"{payload['meta']['compact_lean_speedup_vs_legacy_b2048']:.2f}x")
     print(f"control (closed-loop) vs elastic b2048 gap (min-of-A/B): "
           f"{payload['meta']['control_gap_vs_elastic']:+.1%}")
     print(f"deadline (graceful degradation) vs control b2048 gap "

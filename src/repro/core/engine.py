@@ -26,15 +26,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import elasticity, network, storage
+from . import compaction, elasticity, network, storage
 from .config import (BindingPolicy, Scenario, SchedPolicy,
                      base_task_lengths_f32)
 from .control import (ControlPolicy, DeadlinePolicy, earliest_finish,
                       failover_targets, scenario_control)
 from .telemetry import (EV_FINISH, EV_KILL, EV_PREEMPT, EV_SCALE_CLOSE,
                         EV_SCALE_OPEN, EV_SHED, EV_START, TraceBuffers,
-                        event_capacity, pull, put, timeseries_capacity)
-from .util import pow2_pad, validate_pow2_floor
+                        event_capacity, put, timeseries_capacity)
 
 _BIG = 1e30          # stand-in for +inf that survives arithmetic
 _TIME_EPS = 1e-6     # relative tie window for simultaneous events
@@ -1271,7 +1270,7 @@ def _step_epoch_chunk_impl(batch: ScenarioArrays, inv: _EpochInv,
 
     Returns ``(carry, active, counts, order)`` where ``counts`` is the
     fused ``i32[2] = [epochs_executed, n_still_active]`` pair — the ONLY
-    value the dispatch-lean host loop pulls per round — and ``order`` is
+    value the compaction host loop pulls per round — and ``order`` is
     the on-device active-first permutation (``argsort`` of ``~active``;
     jnp argsort is stable, so it reproduces the host-side
     ``concatenate([nonzero(act), nonzero(~act)])`` order bit for bit).
@@ -1300,10 +1299,10 @@ def _step_epoch_chunk_impl(batch: ScenarioArrays, inv: _EpochInv,
 
 _step_epoch_chunk = jax.jit(_step_epoch_chunk_impl,
                             static_argnames=("k", "control", "trace"))
-# Donating variant (the train/trainer.py idiom): the carry pytree and
-# activity mask buffers are reused in place across rounds instead of
-# copied per chunk.  Safe because the host loop never re-reads a carry
-# it has stepped past (see _compact_loop_lean's store-merge invariant).
+# Donating variant: the carry pytree and activity mask buffers are
+# reused in place across rounds instead of copied per chunk.  Safe
+# because the host loop never re-reads a carry it has stepped past (the
+# store-merge invariant, repro.core.compaction).
 _step_epoch_chunk_donated = jax.jit(_step_epoch_chunk_impl,
                                     static_argnames=("k", "control",
                                                      "trace"),
@@ -1320,44 +1319,24 @@ def _activity_batch(batch: ScenarioArrays, c: _Carry,
     return act, jnp.sum(act, dtype=jnp.int32), jnp.argsort(~act)
 
 
-@jax.jit
-def _take_lanes(tree, idx: jax.Array):
-    """Gather a lane subset of any stacked pytree (exact: pure indexing)."""
-    return jax.tree.map(lambda x: x[idx], tree)
-
-
-def _put_lanes_impl(store, idx: jax.Array, sub):
-    """Scatter a lane subset back into the dense store (distinct indices,
-    so the write order cannot matter)."""
-    return jax.tree.map(lambda s, x: s.at[idx].set(x), store, sub)
-
-
-_put_lanes = jax.jit(_put_lanes_impl)
-# Donates only the store (arg 0): its output leaves match the input
-# shapes exactly so XLA reuses the buffers; ``sub`` is the pad-sized
-# working carry whose shapes differ, and donating unusable buffers just
-# trips jax's donation warning.
-_put_lanes_donated = jax.jit(_put_lanes_impl, donate_argnums=(0,))
-
-
 def simulate_batch_arrays_compact(
         batch: ScenarioArrays, *, k: int | str = "auto",
         floor: int = 8, cost_model=None, control: bool | None = None,
         trace: bool = False, trace_events: int | None = None,
-        stats: dict | None = None, donate: bool = True,
-        legacy: bool = False):
+        stats: dict | None = None):
     """:func:`simulate_batch_arrays` with sparse active-lane compaction.
 
     Tail-heavy batches (mixed-policy / elastic grids) realize 20+ epochs
     while most lanes finish within ~5 — yet the dense driver keeps
     stepping every lane through the long tail because the epoch body is
-    branch-free.  This host-driven variant checks the per-lane activity
-    mask every ``k`` epochs; when the still-active count (pow2-padded,
-    ``floor`` minimum) drops below the current working-set size, the
-    active lanes are gathered into a compacted batch, the same compiled
-    epoch chunk advances only those, and final carries scatter back by
-    original lane index.  A b2048 batch whose tail is 40 active lanes
-    then steps 64 lanes per epoch, not 2048.
+    branch-free.  This driver runs the compaction host loop
+    (:func:`repro.core.compaction.run`) over the compiled epoch chunk:
+    every ``k`` epochs, when the still-active count (pow2-padded,
+    ``floor`` minimum) drops below the working-set size, the active
+    lanes are gathered into a compacted batch, the same compiled chunk
+    advances only those, and final carries scatter back by original
+    lane index.  A b2048 batch whose tail is 40 active lanes then steps
+    64 lanes per epoch, not 2048.
 
     **Bitwise identical** to the dense driver: the vmapped epoch body is
     a per-lane function (gather/scatter cannot change any lane's
@@ -1376,221 +1355,45 @@ def simulate_batch_arrays_compact(
 
     The trace leaves ride the carry through the gather/scatter like any
     other leaf, so traced compacted runs are bitwise-identical to the
-    dense driver's.  ``stats`` (a dict, mutated in place) collects host
-    telemetry for :class:`~repro.core.telemetry.RunReport`: ``syncs``
-    (full mask/permutation device→host pulls — paid only on rounds that
-    actually compact), ``scalar_syncs`` (the per-round fused
-    ``[n_step, n_active]`` scalar pulls), ``compactions`` (gather
-    rounds), ``dispatches`` (chunk-stepper launches),
-    ``lane_epochs_allotted`` (launched lanes × the epochs each chunk may
-    run) and the transfer counts of :func:`~repro.core.telemetry.put` /
-    ``pull``.  The host spans ``iotsim.compact.{prepare,step,poll,
-    regather,finish}`` mark the loop's phases on the profiler's clock
-    (DESIGN.md §12.4).
-
-    ``donate=True`` routes rounds through the buffer-donating stepper /
-    store-scatter jits (carries update in place instead of copying every
-    chunk); ``legacy=True`` runs the pre-dispatch-lean host loop — one
-    full ``bool[N]`` mask pull per round, host-side ordering, no
-    donation — kept as the honest benchmark comparator and the
-    reference semantics for the lean loop's tests.
+    dense driver's.  ``stats`` (a dict, mutated in place) collects the
+    loop's host counters and transfers for
+    :class:`~repro.core.telemetry.RunReport` (keys in
+    :mod:`repro.core.compaction`).  Chunks step through the
+    carry-donating stepper once the loop owns the carry.
     """
     if control is None:
         control = _control_active(batch)
-    if stats is None:
-        stats = {}
-    for key in ("syncs", "scalar_syncs", "compactions", "dispatches",
-                "lane_epochs_allotted"):
-        stats.setdefault(key, 0)
-    N, T = batch.task_job.shape[:2]
-    bound = 2 * T + 2
-    if control:
-        # lanes widen their own epoch bound (_lane_bound, additive per
-        # mechanism); the host budget only needs the batch-wide worst
-        # case — per-lane counts stay exact through the activity mask
-        if bool(np.any(pull(batch.vm_valid, stats)
-                       & (pull(batch.vm_fail, stats) < _BIG / 2))):
-            bound += 2 * T + batch.vm_mips.shape[1]
-        if bool(np.any((pull(batch.deadline_policy, stats)
-                        == int(DeadlinePolicy.SHED))
-                       & np.any(pull(batch.task_valid, stats)
-                                & (pull(batch.task_deadline, stats)
-                                   < _BIG / 2), axis=1))):
-            bound += T + 1
-        if bool(np.any(pull(batch.preempt, stats) != 0)):
-            bound += 2 * T
-    if k == "auto":
-        from . import costmodel as costmodel_mod
-        cm = cost_model or costmodel_mod.default_cost_model()
-        k = cm.compact_interval(N, T)
-    k = int(k)
-    if k < 1:
-        raise ValueError(f"simulate_batch_arrays_compact: k must be >= 1 "
-                         f"or 'auto', got {k}")
-    validate_pow2_floor(floor)
-    tr = _trace_caps(T, batch.vm_mips.shape[1], control, trace,
-                     trace_events)
-    loop = _compact_loop_legacy if legacy else _compact_loop_lean
-    return loop(batch, bound=bound, k=k, floor=floor, control=control,
-                tr=tr, stats=stats, donate=donate)
+    tr = _trace_caps(batch.task_job.shape[1], batch.vm_mips.shape[1],
+                     control, trace, trace_events)
 
-
-def _compact_loop_lean(batch: ScenarioArrays, *, bound: int, k: int,
-                       floor: int, control: bool, tr, stats: dict,
-                       donate: bool):
-    """Dispatch-lean host loop (DESIGN.md §13): one fused scalar pull per
-    round; the full active-first permutation crosses the host boundary
-    only on rounds that actually compact; carries are donated in place.
-
-    Store-merge invariant (what makes donation safe): ``carry_store`` is
-    ``None`` until the first compaction — before that, ``cur_carry`` IS
-    the full batch in original lane order, so there is no N-sized copy
-    aliasing ``c0`` for the donating stepper to invalidate.  Afterwards
-    the store holds exactly the lanes *outside* ``cur_idx`` (plus stale
-    copies of lanes inside it, which every merge overwrites), and the
-    host never re-reads a carry object after passing it to a donating
-    jit — each round rebinds ``cur_carry`` to the stepper's output, and
-    the final ``_output_batch``/``_trace_of`` reads only the merged
-    result, never a donated argument."""
-    N = batch.task_job.shape[0]
-    with jax.profiler.TraceAnnotation("iotsim.compact.prepare"):
+    def prepare():
         inv, c0 = _setup_batch(batch, control=control, trace=tr)
-        cur_batch, cur_inv, cur_carry = batch, inv, c0
-        cur_active, n_act_dev, order_dev = _activity_batch(batch, c0,
-                                                           control=control)
-        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
-            n_act = int(pull(n_act_dev, stats))
-        stats["scalar_syncs"] += 1
-    carry_store = None
-    # freshness flags: ``_epoch_setup``/``initial_state``-style jits can
-    # forward an input array unchanged, so the t=0 carry may alias batch
-    # leaves — donating a buffer that also rides in the same call's
-    # operands is an XLA error.  Only carries/stores produced by a
-    # compute op inside this loop (gather or stepper output) are donated.
-    carry_fresh = store_fresh = False
-    cur_idx = np.arange(N)
-    realized = 0
-    while realized < bound:
-        if n_act == 0:
-            break
-        pad = pow2_pad(n_act, cap=len(cur_idx), floor=floor)
-        if pad < len(cur_idx):
-            # retire the working set into the dense store, then gather the
-            # active lanes (pow2-padded with finished lanes, which step
-            # idempotently) into a compacted view of the original batch —
-            # the device-computed order crosses the host boundary here
-            # and only here
-            with jax.profiler.TraceAnnotation("iotsim.compact.regather"):
-                order = pull(order_dev, stats)[:pad]
-                stats["syncs"] += 1
-                if carry_store is None:
-                    carry_store, store_fresh = cur_carry, carry_fresh
-                else:
-                    carry_store = (_put_lanes_donated
-                                   if donate and store_fresh
-                                   else _put_lanes)(carry_store,
-                                                    put(cur_idx, stats),
-                                                    cur_carry)
-                    store_fresh = True
-                cur_idx = cur_idx[order]
-                take = put(cur_idx, stats)
-                cur_batch = _take_lanes(batch, take)
-                cur_inv = _take_lanes(inv, take)
-                cur_carry = _take_lanes(carry_store, take)
-                carry_fresh = True
-                cur_active = _active_batch(cur_batch, cur_carry,
-                                           control=control)
-                stats["compactions"] += 1
-        step = (_step_epoch_chunk_donated if donate and carry_fresh
-                else _step_epoch_chunk)
-        limit = min(k, bound - realized)    # epochs the chunk may run
-        stats["lane_epochs_allotted"] += len(cur_idx) * limit
-        with jax.profiler.TraceAnnotation("iotsim.compact.step",
-                                          lanes=len(cur_idx),
-                                          epoch_limit=limit):
-            cur_carry, cur_active, counts, order_dev = step(
-                cur_batch, cur_inv, cur_carry, cur_active,
-                put(np.int32(bound - realized), stats), k, control=control,
-                trace=tr is not None)
-        carry_fresh = True
-        stats["dispatches"] += 1
-        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
-            n_step, n_act = (int(v) for v in pull(counts, stats))
-        stats["scalar_syncs"] += 1
-        realized += n_step
-    with jax.profiler.TraceAnnotation("iotsim.compact.finish"):
-        if carry_store is None:
-            final = cur_carry
-        else:
-            final = (_put_lanes_donated if donate and store_fresh
-                     else _put_lanes)(carry_store, put(cur_idx, stats),
-                                      cur_carry)
+        return ((batch, inv), c0,
+                *_activity_batch(batch, c0, control=control))
+
+    def stepper(chunk):
+        def step(lanes, carry, active, k, remaining):
+            return chunk(*lanes, carry, active,
+                         put(np.int32(remaining), stats), k,
+                         control=control, trace=tr is not None)
+        return step
+
+    def regathered(lanes, carry):
+        return _active_batch(lanes[0], carry, control=control)
+
+    def finish(final, realized):
         out = _output_batch(batch, final), put(np.int32(realized), stats)
         if tr is not None:
             return out + (_trace_of(final),)
         return out
 
-
-def _compact_loop_legacy(batch: ScenarioArrays, *, bound: int, k: int,
-                         floor: int, control: bool, tr, stats: dict,
-                         donate: bool):
-    """The pre-dispatch-lean host loop: a full ``bool[N]`` mask pull +
-    host-side ordering every round, no donation.  Kept as the honest A/B
-    comparator for the recorded compaction benches and as the reference
-    the lean loop's bitwise tests pin against.  Its per-round mask pull
-    sits in the ``iotsim.compact.regather`` span."""
-    del donate                     # the legacy loop never donated
-    N = batch.task_job.shape[0]
-    with jax.profiler.TraceAnnotation("iotsim.compact.prepare"):
-        inv, c0 = _setup_batch(batch, control=control, trace=tr)
-        carry_store = c0
-        cur_batch, cur_inv, cur_carry = batch, inv, c0
-        cur_active = _active_batch(batch, c0, control=control)
-    cur_idx = np.arange(N)
-    realized = 0
-    while realized < bound:
-        with jax.profiler.TraceAnnotation("iotsim.compact.regather"):
-            act_np = pull(cur_active, stats)
-            stats["syncs"] += 1
-            n_act = int(act_np.sum())
-            if n_act == 0:
-                break
-            pad = pow2_pad(n_act, cap=len(cur_idx), floor=floor)
-            if pad < len(cur_idx):
-                carry_store = _put_lanes(carry_store, put(cur_idx, stats),
-                                         cur_carry)
-                order = np.concatenate([np.nonzero(act_np)[0],
-                                        np.nonzero(~act_np)[0]])[:pad]
-                cur_idx = cur_idx[order]
-                take = put(cur_idx, stats)
-                cur_batch = _take_lanes(batch, take)
-                cur_inv = _take_lanes(inv, take)
-                cur_carry = _take_lanes(carry_store, take)
-                cur_active = _active_batch(cur_batch, cur_carry,
-                                           control=control)
-                stats["compactions"] += 1
-        limit = min(k, bound - realized)    # epochs the chunk may run
-        stats["lane_epochs_allotted"] += len(cur_idx) * limit
-        with jax.profiler.TraceAnnotation("iotsim.compact.step",
-                                          lanes=len(cur_idx),
-                                          epoch_limit=limit):
-            cur_carry, cur_active, counts, _ = _step_epoch_chunk(
-                cur_batch, cur_inv, cur_carry, cur_active,
-                put(np.int32(bound - realized), stats), k, control=control,
-                trace=tr is not None)
-        stats["dispatches"] += 1
-        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
-            n_step = int(pull(counts[0], stats))
-        stats["scalar_syncs"] += 1
-        realized += n_step
-    with jax.profiler.TraceAnnotation("iotsim.compact.finish"):
-        carry_store = _put_lanes(carry_store, put(cur_idx, stats),
-                                 cur_carry)
-        out = (_output_batch(batch, carry_store),
-               put(np.int32(realized), stats))
-        if tr is not None:
-            return out + (_trace_of(carry_store),)
-        return out
+    return compaction.run(
+        batch, k=k, floor=floor, control=control, cost_model=cost_model,
+        stats=stats, prepare=prepare,
+        step=stepper(_step_epoch_chunk),
+        step_donated=stepper(_step_epoch_chunk_donated),
+        read=lambda counts, limit: (int(counts[0]), int(counts[1])),
+        regathered=regathered, finish=finish)
 
 
 # ---------------------------------------------------------------------------

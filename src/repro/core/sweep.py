@@ -63,6 +63,7 @@ from .control import ControlPolicy, as_control_policy
 from .control import DeadlinePolicy, as_deadline_policy
 from .control import failure_times as _failure_times
 from .elasticity import ElasticitySpec, as_arrival_process
+from ..kernels.mr_sched import ops as mr_ops
 from .engine import (_BIG, JobMetrics, ScenarioArrays, ScenarioMetrics,
                      bind_tasks, from_scenario, job_metrics,
                      scenario_metrics, simulate_arrays,
@@ -1484,9 +1485,8 @@ def _fused_runner(layout: tuple, pad_tasks: int, pad_vms: int,
             batch = jax.vmap(one)(*_unpack_columns(buf, layout))
         with jax.named_scope("epoch_loop"):
             if backend == "pallas":
-                from repro.kernels.mr_sched import \
-                    epoch_schedule  # lazy: ref.py cycle
-                out = epoch_schedule(batch, max_pes=max_pes, control=control)
+                out = mr_ops.epoch_schedule(batch, max_pes=max_pes,
+                                            control=control)
                 realized = jnp.max(out.n_epochs)
             else:
                 out, realized = simulate_batch_arrays(batch, control=control)
@@ -1528,11 +1528,9 @@ def _run_compact(cols: dict[str, np.ndarray], pad_tasks: int, pad_vms: int,
     batch = grid_arrays(cols, pad_tasks=pad_tasks, pad_vms=pad_vms,
                         static_params=statics, stats=stats)
     if backend == "pallas":
-        from repro.kernels.mr_sched import \
-            epoch_schedule_compact  # lazy: ref.py cycle
-        out, realized = epoch_schedule_compact(batch, k=k, max_pes=max_pes,
-                                               cost_model=cost,
-                                               control=control, stats=stats)
+        out, realized = mr_ops.epoch_schedule_compact(
+            batch, k=k, max_pes=max_pes, cost_model=cost, control=control,
+            stats=stats)
     else:
         out, realized = simulate_batch_arrays_compact(batch, k=k,
                                                       cost_model=cost,
@@ -1585,57 +1583,43 @@ def _run_cells(cols: dict[str, np.ndarray], n: int, pad_tasks: int,
     max_pes = (max(int(np.ceil(float(np.max(cols["vm_pes"])))), 1)
                if backend == "pallas" else 0)
     if compact is not None:
-        if chunk is not None:
-            parts, realized = [], np.empty(n, np.int32)
-            for lo in range(0, n, chunk):
-                part = _pad_cells(
-                    {k: v[lo:lo + chunk] for k, v in cols.items()},
-                    min(chunk, n))
-                take = min(chunk, n - lo)
-                jm, sm, rz = _run_compact(part, pad_tasks, pad_vms, statics,
-                                          backend, compact, cost, max_pes,
-                                          control, stats)
-                parts.append(jax.tree.map(lambda x: x[:take], (jm, sm)))
-                realized[lo:lo + take] = rz
-            jm, sm = jax.tree.map(lambda *xs: np.concatenate(xs), *parts)
-            return jm, sm, realized
-        jm, sm, rz = _run_compact(cols, pad_tasks, pad_vms, statics,
-                                  backend, compact, cost, max_pes, control,
-                                  stats)
+        def launch(part):
+            return _run_compact(part, pad_tasks, pad_vms, statics, backend,
+                                compact, cost, max_pes, control, stats)
+    else:
+        layout = _upload_layout(cols, sorted(cols))
+        runner = _fused_runner(layout, pad_tasks, pad_vms,
+                               tuple(sorted((statics or {}).items())),
+                               backend, max_pes, control)
+
+        def launch(part):
+            """Upload, run and read back one batch of cells, one transfer
+            each way; ``(jm, sm, realized)`` on the host."""
+            with jax.profiler.TraceAnnotation("iotsim.upload"):
+                buf = telemetry.put(_pack_columns(part, layout), stats)
+            with jax.profiler.TraceAnnotation("iotsim.launch"):
+                launched = runner(buf)
+            stats["dispatches"] += 1
+            with jax.profiler.TraceAnnotation("iotsim.readback"):
+                jm, sm, rz = runner.pull(launched, stats)
+            rz = int(rz)
+            stats["lane_epochs_allotted"] += (
+                _lanes(len(part[layout[0][0]]), backend) * rz)
+            return jm, sm, rz
+
+    if chunk is None:
+        jm, sm, rz = launch(cols)
         return jm, sm, np.full(n, rz, np.int32)
-    layout = _upload_layout(cols, sorted(cols))
-    runner = _fused_runner(layout, pad_tasks, pad_vms,
-                           tuple(sorted((statics or {}).items())),
-                           backend, max_pes, control)
-
-    def launch(part):
-        """Upload, run and read back one batch of cells, one transfer each
-        way; ``(jm, sm, realized)`` on the host."""
-        with jax.profiler.TraceAnnotation("iotsim.upload"):
-            buf = telemetry.put(_pack_columns(part, layout), stats)
-        with jax.profiler.TraceAnnotation("iotsim.launch"):
-            launched = runner(buf)
-        stats["dispatches"] += 1
-        with jax.profiler.TraceAnnotation("iotsim.readback"):
-            jm, sm, rz = runner.pull(launched, stats)
-        rz = int(rz)
-        stats["lane_epochs_allotted"] += (
-            _lanes(len(part[layout[0][0]]), backend) * rz)
-        return jm, sm, rz
-
-    if chunk is not None:
-        parts, realized = [], np.empty(n, np.int32)
-        for lo in range(0, n, chunk):
-            part = _pad_cells({k: v[lo:lo + chunk] for k, v in cols.items()},
-                              min(chunk, n))
-            take = min(chunk, n - lo)
-            jm, sm, rz = launch(part)
-            parts.append(jax.tree.map(lambda x: x[:take], (jm, sm)))
-            realized[lo:lo + take] = rz
-        jm, sm = jax.tree.map(lambda *xs: np.concatenate(xs), *parts)
-        return jm, sm, realized
-    jm, sm, rz = launch(cols)
-    return jm, sm, np.full(n, rz, np.int32)
+    parts, realized = [], np.empty(n, np.int32)
+    for lo in range(0, n, chunk):
+        part = _pad_cells({k: v[lo:lo + chunk] for k, v in cols.items()},
+                          min(chunk, n))
+        take = min(chunk, n - lo)
+        jm, sm, rz = launch(part)
+        parts.append(jax.tree.map(lambda x: x[:take], (jm, sm)))
+        realized[lo:lo + take] = rz
+    jm, sm = jax.tree.map(lambda *xs: np.concatenate(xs), *parts)
+    return jm, sm, realized
 
 
 def _lanes(n: int, backend: str) -> int:
@@ -1644,8 +1628,7 @@ def _lanes(n: int, backend: str) -> int:
     batch as given."""
     if backend != "pallas":
         return n
-    from repro.kernels.mr_sched import ops  # lazy: ref.py cycle
-    return n + ops.lane_pad(n, ops.resolve_mode(None, None)[1])
+    return n + mr_ops.lane_pad(n, mr_ops.resolve_mode(None, None)[1])
 
 
 def _plain_label(v):

@@ -8,7 +8,7 @@ admission are fused into a single kernel body whose per-VM/per-task state
 in VMEM across epochs — the XLA engine (``repro.core.engine``) round-trips
 that state through HBM once per epoch.
 
-Two structural upgrades over the PR-1 ``mr_schedule`` kernel:
+Two structural choices set it apart from a plain port of the engine epoch:
 
 * **Tile-level early exit** — the epoch loop is a ``lax.while_loop`` gated
   on ``any(lane unfinished)`` (plus the ``2T + 2`` safety bound), so a tile

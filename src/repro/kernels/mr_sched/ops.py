@@ -2,14 +2,12 @@
 
 The derived per-task quantities (task lengths, stage-in readiness,
 shuffle delays) are computed in plain jnp — cheap, O(N·T) — and the
-event-loop hot path runs in a Pallas kernel:
-
-* :func:`schedule` — the PR-1 ``mr_schedule`` kernel (static ``2T + 2``
-  epoch bound, T×T admission rank), returns ``(start, finish)``;
-* :func:`epoch_schedule` — the fused ``mr_epoch`` megakernel (tile-level
-  early exit + per-VM admission scan), returns a full
-  :class:`~repro.core.engine.SimOutput` so the sweep metrics layers can
-  consume it directly (``SweepPlan.run(backend="pallas")``).
+event-loop hot path runs in the fused ``mr_epoch`` megakernel (tile-level
+early exit + per-VM admission scan).  :func:`epoch_schedule` returns a
+full :class:`~repro.core.engine.SimOutput` so the sweep metrics layers
+can consume it directly (``SweepPlan.run(backend="pallas")``);
+:func:`epoch_schedule_compact` runs the kernel under the compaction host
+loop (:mod:`repro.core.compaction`).
 """
 from __future__ import annotations
 
@@ -19,14 +17,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import network, storage
+from repro.core import compaction, network, storage
 from repro.core.control import failover_targets
-from repro.core.engine import (ScenarioArrays, SimOutput, _take_lanes,
-                               _put_lanes, _put_lanes_donated)
-from repro.core.telemetry import pull, put, timeseries_capacity
-from repro.core.util import pow2_pad, validate_pow2_floor
+from repro.core.engine import ScenarioArrays, SimOutput
+from repro.core.telemetry import pull, timeseries_capacity
 
-from .kernel import mr_schedule
 from .megakernel import _BIG, initial_state, mr_epoch, mr_epoch_donated
 
 
@@ -97,23 +92,6 @@ def lane_pad(n: int, tile: int) -> int:
     """Empty lanes that pad ``n`` lanes to whole ``tile``-lane blocks (a
     batch smaller than a tile is one block of its own size)."""
     return (-n) % min(tile, max(n, 1))
-
-
-def schedule(batch: ScenarioArrays, *, tile: int = 64,
-             interpret: bool | None = None):
-    """batch: stacked single-job scenarios (leading dim N)."""
-    interpret, tile = resolve_mode(interpret, tile)
-    task_len, ready0, shuffle = _derived_inputs(batch)
-    return mr_schedule(
-        task_len.astype(jnp.float32), batch.task_vm.astype(jnp.int32),
-        ready0.astype(jnp.float32),
-        batch.task_is_reduce.astype(jnp.int32),
-        batch.task_valid.astype(jnp.int32),
-        shuffle.astype(jnp.float32)[:, None],
-        batch.vm_mips.astype(jnp.float32),
-        batch.vm_pes.astype(jnp.float32),
-        batch.sched_policy.astype(jnp.int32)[:, None],
-        tile=tile, interpret=interpret)
 
 
 def _control_lane_data(batch: ScenarioArrays, pad, task_vm2, refetch):
@@ -336,13 +314,13 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
                            interpret: bool | None = None, floor: int = 8,
                            cost_model=None, control: bool = False,
                            trace: bool = False, stats: dict | None = None,
-                           donate: bool = True,
                            block_lanes: int | None = None):
     """Sparse active-lane compaction over the ``mr_epoch`` megakernel
     (DESIGN.md §9) — the Pallas twin of
-    ``engine.simulate_batch_arrays_compact``.
+    ``engine.simulate_batch_arrays_compact``, on the same host loop
+    (:func:`repro.core.compaction.run`).
 
-    A host loop steps the batch in ``k``-epoch chunks through the
+    The loop steps the batch in ``k``-epoch chunks through the
     *resumable* kernel (``state`` in/out, static ``epoch_limit``).  After
     each chunk the still-active lanes are gathered front-first into a
     pow2-padded compacted batch — re-tiled automatically, since the
@@ -358,155 +336,63 @@ def epoch_schedule_compact(batch: ScenarioArrays, *, k="auto",
     path exposes).
 
     ``control=True`` composes the closed loop with compaction
-    (DESIGN.md §10): killed-then-restored lanes stay in the host-side
-    active set (their tasks are unfinished), so a failure that re-opens
-    work after a lane looked nearly done simply keeps the lane in the
-    gather — the epoch body stays idempotent for finished lanes and the
-    result stays bitwise identical to the dense control path.  The host
-    bound widens to the control epoch bound; the kernel's per-lane bound
-    keeps degenerate lanes' realized counts at the open-loop ``2T + 2``.
+    (DESIGN.md §10): killed-then-restored lanes stay in the active set
+    (their tasks are unfinished), so a failure that re-opens work after
+    a lane looked nearly done simply keeps the lane in the gather.  The
+    host bound widens to the control epoch bound; the kernel's per-lane
+    bound keeps degenerate lanes' realized counts at the open-loop
+    ``2T + 2``.
 
     ``trace=True`` (DESIGN.md §12): the time-series leaf rides the
     gather/scatter like any other carry leaf, so the rows stay bitwise
     the dense traced path's; returns ``(SimOutput, realized, ts)``.
 
-    ``stats`` (a dict, mutated in place) collects host-loop counters
-    with the engine compact driver's keys — ``syncs`` (full permutation
-    device→host pulls, paid only on rounds that actually compact),
-    ``scalar_syncs`` (the per-round still-active scalar pulls),
-    ``compactions`` (gather/scatter re-tiles), ``dispatches`` (kernel
-    chunk launches), ``lane_epochs_allotted`` (launched lanes ×
-    ``epoch_limit``, per chunk) and the transfer counts of
-    :func:`~repro.core.telemetry.put`/``pull`` — feeding the sweep
-    :class:`~repro.core.telemetry.RunReport`.  The host spans
-    ``iotsim.compact.{prepare,step,poll,regather,finish}`` mark the
-    loop's phases on the profiler's clock (DESIGN.md §12.4).
-
-    ``donate=True`` steps chunks through the state-donating kernel jit
-    (``mr_epoch_donated``) and the donating store-scatter, so the carry
-    updates in place instead of copying every chunk (the engine lean
-    loop's store-merge invariant, see
-    ``engine._compact_loop_lean``).  The t=0 state is never donated:
-    ``initial_state`` forwards lane arrays as state leaves.
+    ``stats`` (a dict, mutated in place) collects the loop's host
+    counters and transfers, with the engine driver's keys.  Chunks step
+    through the state-donating ``mr_epoch_donated`` once the loop owns
+    the state; the t=0 state is never donated, since ``initial_state``
+    forwards lane arrays as state leaves.
 
     The set-up and output glue run as one compiled program each,
     :func:`_compact_prepare` and :func:`_compact_finish`, whose shapes
-    are fixed by the batch.  Only the final store merge (``_put_lanes``)
-    stays a call of its own: its index length is the last round's pow2
-    pad, which varies from call to call, and would otherwise multiply
-    the output program's compile variants.
+    are fixed by the batch.  Only the final store merge stays a call of
+    its own: its index length is the last round's pow2 pad, which varies
+    from call to call, and would otherwise multiply the output program's
+    compile variants.
     """
-    if stats is None:
-        stats = {}
-    stats.setdefault("syncs", 0)
-    stats.setdefault("scalar_syncs", 0)
-    stats.setdefault("compactions", 0)
-    stats.setdefault("dispatches", 0)
-    stats.setdefault("lane_epochs_allotted", 0)
-    validate_pow2_floor(floor)
     interpret, tile = resolve_mode(interpret, tile)
     if max_pes is None:
         max_pes = max(int(np.ceil(float(pull(jnp.max(batch.vm_pes),
                                              stats)))), 1)
     N, T = batch.task_vm.shape
-    V = batch.vm_mips.shape[1]
-    # host budget = the batch-wide worst case of the additive per-lane
-    # bound (engine.simulate_batch_arrays_compact's exact host rule);
-    # per-lane counts stay exact through the kernel's lane_bound
-    bound = 2 * T + 2
-    if control:
-        if bool(np.any(pull(batch.vm_valid, stats)
-                       & (pull(batch.vm_fail, stats) < _BIG / 2))):
-            bound += 2 * T + V
-        if bool(np.any((pull(batch.deadline_policy, stats) == 1)
-                       & np.any(pull(batch.task_valid, stats)
-                                & (pull(batch.task_deadline, stats)
-                                   < _BIG / 2), axis=1))):
-            bound += T + 1
-        if bool(np.any(pull(batch.preempt, stats) != 0)):
-            bound += 2 * T
-    if k == "auto":
-        from repro.core import costmodel as costmodel_mod
-        cm = cost_model or costmodel_mod.default_cost_model()
-        k = cm.compact_interval(N, T)
-    k = int(k)
-    if k < 1:
-        raise ValueError(f"epoch_schedule_compact: k must be >= 1, got {k}")
-    with jax.profiler.TraceAnnotation("iotsim.compact.prepare"):
-        n_pad = lane_pad(N, tile)
-        lanes, cur_state, n_act_dev, order_dev = _compact_prepare(
-            batch, n_pad=n_pad, control=control,
-            trace_capacity=(timeseries_capacity(T, V, control) if trace
-                            else None))
-        # ``store`` is None until the first compaction (before that,
-        # ``cur_state`` IS the dense store in original lane order) — the
-        # engine lean loop's store-merge invariant, which is what makes
-        # donating ``cur_state`` into each chunk safe: no N-sized alias of
-        # the donated carry ever exists on the host side.  The freshness
-        # flags guard the other aliasing hazard: ``initial_state``
-        # forwards some lane arrays as state leaves unchanged (state[1]
-        # IS task_len), so the t=0 state out of ``_compact_prepare`` may
-        # share buffers with the lane operands, and donating a buffer
-        # that also rides in the same call's lane operands is an XLA
-        # error — so only carries/stores produced by a compute op inside
-        # this loop are ever donated.
-        store = None
-        state_fresh = store_fresh = False
-        cur_idx = np.arange(N + n_pad)
-        cur_lanes = lanes
-        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
-            n_act = int(pull(n_act_dev, stats))
-        stats["scalar_syncs"] += 1
-    total = 0
-    while total < bound:
-        if n_act == 0:
-            break
-        pad_n = pow2_pad(n_act, cap=len(cur_idx), floor=floor)
-        if pad_n < len(cur_idx):
-            # active lanes first; the pow2 padding is filled with
-            # finished lanes, which step idempotently — the
-            # device-computed order crosses the host boundary here and
-            # only here
-            with jax.profiler.TraceAnnotation("iotsim.compact.regather"):
-                order = pull(order_dev, stats)[:pad_n]
-                stats["syncs"] += 1
-                if store is None:
-                    store, store_fresh = cur_state, state_fresh
-                else:
-                    store = (_put_lanes_donated if donate and store_fresh
-                             else _put_lanes)(store, put(cur_idx, stats),
-                                              cur_state)
-                    store_fresh = True
-                cur_idx = cur_idx[order]
-                take = put(cur_idx, stats)
-                cur_lanes = _take_lanes(lanes, take)
-                cur_state = _take_lanes(store, take)
-                state_fresh = True
-                stats["compactions"] += 1
-        limit = min(k, bound - total)
-        stats["dispatches"] += 1
-        stats["lane_epochs_allotted"] += len(cur_idx) * limit
-        step = mr_epoch_donated if donate and state_fresh else mr_epoch
-        with jax.profiler.TraceAnnotation("iotsim.compact.step",
-                                          lanes=len(cur_idx),
-                                          epoch_limit=limit):
-            cur_state = step(*cur_lanes[:2], None, *cur_lanes[2:],
-                             state=cur_state, tile=tile, max_pes=max_pes,
-                             interpret=interpret, epoch_limit=limit,
-                             control=control, trace=trace,
-                             block_lanes=block_lanes)
-            state_fresh = True
-            total += limit
-            n_act_dev, order_dev = _state_activity(
-                cur_lanes[3], cur_state[4],
-                cur_state[12] if control else None)
-        with jax.profiler.TraceAnnotation("iotsim.compact.poll"):
-            n_act = int(pull(n_act_dev, stats))
-        stats["scalar_syncs"] += 1
-    with jax.profiler.TraceAnnotation("iotsim.compact.finish"):
-        if store is None:
-            store = cur_state
-        else:
-            store = (_put_lanes_donated if donate and store_fresh
-                     else _put_lanes)(store, put(cur_idx, stats), cur_state)
-        return _compact_finish(batch, store, control=control, trace=trace)
+    trace_capacity = (timeseries_capacity(T, batch.vm_mips.shape[1], control)
+                      if trace else None)
+
+    def prepare():
+        lanes, state, n_act, order = _compact_prepare(
+            batch, n_pad=lane_pad(N, tile), control=control,
+            trace_capacity=trace_capacity)
+        return (lanes,), state, None, n_act, order
+
+    def stepper(donated: bool):
+        def step(lanes, state, aux, k, remaining):
+            (ln,) = lanes
+            # looked up at call time, so the module attributes can be
+            # replaced (the benchmark's fault injection does)
+            kernel = mr_epoch_donated if donated else mr_epoch
+            state = kernel(*ln[:2], None, *ln[2:], state=state, tile=tile,
+                           max_pes=max_pes, interpret=interpret,
+                           epoch_limit=min(k, remaining), control=control,
+                           trace=trace, block_lanes=block_lanes)
+            return (state, aux,
+                    *_state_activity(ln[3], state[4],
+                                     state[12] if control else None))
+        return step
+
+    return compaction.run(
+        batch, k=k, floor=floor, control=control, cost_model=cost_model,
+        stats=stats, prepare=prepare, step=stepper(False),
+        step_donated=stepper(True),
+        read=lambda n_act, limit: (limit, int(n_act)),
+        finish=lambda store, realized: _compact_finish(
+            batch, store, control=control, trace=trace))

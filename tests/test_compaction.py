@@ -32,7 +32,8 @@ try:
 except ImportError:              # environment without hypothesis: the
     HAVE_HYPOTHESIS = False      # seeded-rng cases below still run
 
-from repro.core import BindingPolicy, SchedPolicy, costmodel, engine, sweep
+from repro.core import (BindingPolicy, SchedPolicy, compaction, costmodel,
+                        engine, sweep)
 from repro.core.engine import _BIG
 from repro.core.sweep import axis, product, zip_
 from repro.core.util import pow2_pad, pow2_pads
@@ -508,13 +509,13 @@ def _check_take_put_roundtrip(seed: int):
         "trace_off": None,
     }
     idx = jnp.asarray(rng.permutation(n)[:m])
-    sub = engine._take_lanes(tree, idx)
-    back = engine._put_lanes(tree, idx, sub)
+    sub = compaction._take_lanes(tree, idx)
+    back = compaction._put_lanes(tree, idx, sub)
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), tree, back)
     # distinct-index scatter of gathered rows is exact, so double
     # application changes nothing either
-    again = engine._put_lanes(back, idx, engine._take_lanes(back, idx))
+    again = compaction._put_lanes(back, idx, compaction._take_lanes(back, idx))
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), tree, again)
 
@@ -539,7 +540,7 @@ def test_take_put_roundtrip_real_carry():
     _, c0 = engine._setup_batch(batch)
     rng = np.random.default_rng(0)
     idx = jnp.asarray(rng.permutation(12)[:8])
-    back = engine._put_lanes(c0, idx, engine._take_lanes(c0, idx))
+    back = compaction._put_lanes(c0, idx, compaction._take_lanes(c0, idx))
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), c0, back)
 
@@ -549,22 +550,18 @@ def test_take_put_roundtrip_real_carry():
 # ---------------------------------------------------------------------------
 
 def test_engine_compact_donation_safe_and_bitwise():
-    """donate=True must consume only loop-internal buffers: results match
-    the donation-off and legacy loops bitwise, every output fully
-    materializes, and a second run over the SAME batch arrays (shared,
-    never donated) is identical — a use-after-donate anywhere raises."""
+    """The donating loop must consume only loop-internal buffers: results
+    match the dense driver bitwise, every output fully materializes, and
+    a second run over the SAME batch arrays (shared, never donated) is
+    identical — a use-after-donate anywhere raises."""
     batch = sweep.grid_arrays(_elastic_params(48, seed=23),
                               pad_tasks=23, pad_vms=9)
-    lean, r1 = engine.simulate_batch_arrays_compact(batch, k=2)
-    off, r2 = engine.simulate_batch_arrays_compact(batch, k=2,
-                                                   donate=False)
-    legacy, r3 = engine.simulate_batch_arrays_compact(batch, k=2,
-                                                      legacy=True)
-    again, r4 = engine.simulate_batch_arrays_compact(batch, k=2)
-    _assert_bitwise(lean, off, "donate on vs off")
-    _assert_bitwise(lean, legacy, "lean vs legacy loop")
-    _assert_bitwise(lean, again, "repeat over shared batch")
-    assert int(r1) == int(r2) == int(r3) == int(r4)
+    comp, r1 = engine.simulate_batch_arrays_compact(batch, k=2)
+    dense, r2 = jax.jit(engine.simulate_batch_arrays)(batch)
+    again, r3 = engine.simulate_batch_arrays_compact(batch, k=2)
+    _assert_bitwise(comp, dense, "compact vs dense driver")
+    _assert_bitwise(comp, again, "repeat over shared batch")
+    assert int(r1) == int(r2) == int(r3)
 
 
 def test_engine_compact_donation_safe_traced():
@@ -574,9 +571,8 @@ def test_engine_compact_donation_safe_traced():
                               pad_tasks=23, pad_vms=9)
     out_a, rz_a, tr_a = engine.simulate_batch_arrays_compact(
         batch, k=2, trace=True)
-    out_b, rz_b, tr_b = engine.simulate_batch_arrays_compact(
-        batch, k=2, trace=True, legacy=True)
-    _assert_bitwise(out_a, out_b, "traced lean vs legacy")
+    out_b, rz_b, tr_b = engine.simulate_batch_arrays(batch, trace=True)
+    _assert_bitwise(out_a, out_b, "traced compact vs dense driver")
     assert int(rz_a) == int(rz_b)
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), tr_a, tr_b)
@@ -585,13 +581,12 @@ def test_engine_compact_donation_safe_traced():
 def test_pallas_compact_donation_safe_and_bitwise():
     batch = sweep.grid_arrays(_random_params(48, seed=7),
                               pad_tasks=23, pad_vms=9)
-    lean, r1 = epoch_schedule_compact(batch, k=2, tile=8, interpret=True)
-    off, r2 = epoch_schedule_compact(batch, k=2, tile=8, interpret=True,
-                                     donate=False)
+    comp, r1 = epoch_schedule_compact(batch, k=2, tile=8, interpret=True)
+    dense = epoch_schedule(batch, tile=8, interpret=True)
     again, r3 = epoch_schedule_compact(batch, k=2, tile=8, interpret=True)
-    _assert_bitwise(lean, off, "pallas donate on vs off")
-    _assert_bitwise(lean, again, "pallas repeat over shared batch")
-    assert int(r1) == int(r2) == int(r3)
+    _assert_bitwise(comp, dense, "pallas compact vs dense kernel")
+    _assert_bitwise(comp, again, "pallas repeat over shared batch")
+    assert int(r1) == int(r3) == int(np.asarray(dense.n_epochs).max())
 
 
 def test_run_modes_survive_repeat_with_donation():
@@ -609,35 +604,26 @@ def test_run_modes_survive_repeat_with_donation():
 
 
 # ---------------------------------------------------------------------------
-# Host chattiness: the dispatch-lean loop's sync census (ISSUE 10)
+# Host chattiness: the compaction loop's sync census
 # ---------------------------------------------------------------------------
 
-def test_lean_loop_sync_census():
-    """Acceptance: full mask/permutation pulls drop to <= the number of
-    compaction rounds; every round pays exactly one fused scalar pull."""
+_COMPACT_DRIVERS = {
+    "xla": lambda batch, **kw: engine.simulate_batch_arrays_compact(batch,
+                                                                    **kw),
+    "pallas": lambda batch, **kw: epoch_schedule_compact(
+        batch, tile=8, interpret=True, **kw),
+}
+
+
+@pytest.mark.parametrize("backend", sorted(_COMPACT_DRIVERS))
+def test_lean_loop_sync_census(backend):
+    """Full permutation pulls happen only on compaction rounds; every
+    round pays exactly one scalar pull, plus the initial poll."""
     batch = sweep.grid_arrays(_random_params(64, seed=7),
                               pad_tasks=23, pad_vms=9)
     st = {}
-    engine.simulate_batch_arrays_compact(batch, k=1, stats=st)
+    _COMPACT_DRIVERS[backend](batch, k=1, stats=st)
     assert st["compactions"] > 0, "grid must actually compact"
-    assert st["syncs"] == st["compactions"]
-    assert st["scalar_syncs"] == st["dispatches"] + 1
-    # the legacy loop paid a full-array pull every round
-    stl = {}
-    engine.simulate_batch_arrays_compact(batch, k=1, stats=stl,
-                                         legacy=True)
-    assert stl["compactions"] == st["compactions"]
-    assert stl["dispatches"] == st["dispatches"]
-    assert stl["syncs"] > st["syncs"]
-    assert stl["syncs"] >= stl["dispatches"]
-
-
-def test_pallas_lean_loop_sync_census():
-    batch = sweep.grid_arrays(_random_params(64, seed=7),
-                              pad_tasks=23, pad_vms=9)
-    st = {}
-    epoch_schedule_compact(batch, k=1, tile=8, interpret=True, stats=st)
-    assert st["compactions"] > 0
     assert st["syncs"] == st["compactions"]
     assert st["scalar_syncs"] == st["dispatches"] + 1
 

@@ -156,10 +156,11 @@ def _random_batch(n, seed=0, mixed_policies=False):
 
 
 def _assert_schedule_matches(batch, tile):
-    from repro.kernels.mr_sched import schedule
-    from repro.kernels.mr_sched.ref import schedule_ref
-    s_ref, f_ref = schedule_ref(batch)
-    s_got, f_got = schedule(batch, tile=tile)
+    from repro.core import engine
+    from repro.kernels.mr_sched import epoch_schedule
+    ref = jax.vmap(engine.simulate_arrays)(batch)
+    got = epoch_schedule(batch, tile=tile)
+    s_ref, f_ref, s_got, f_got = ref.start, ref.finish, got.start, got.finish
     valid = np.asarray(batch.task_valid)
     np.testing.assert_allclose(np.where(valid, s_got, 0),
                                np.where(valid, np.asarray(s_ref), 0),
@@ -184,9 +185,10 @@ def test_mr_sched_matches_engine_mixed_policies(tile):
 def test_mr_sched_reproduces_paper_metrics():
     """Kernel schedule -> paper Table IV numbers end to end."""
     from repro.core import sweep
-    from repro.kernels.mr_sched import schedule
+    from repro.kernels.mr_sched import epoch_schedule
     batch = sweep.product(sweep.axis("n_maps", range(1, 11))).arrays()
-    s, f = schedule(batch, tile=8)
+    out = epoch_schedule(batch, tile=8)
+    s, f = out.start, out.finish
     # delay time for M1R1: last map start + reduce start - last map finish
     valid = np.asarray(batch.task_valid)
     for i, m in enumerate(range(1, 11)):

@@ -515,16 +515,25 @@ def test_run_lane_epoch_counters(kw):
             assert b.lane_epochs_allotted in {lanes * r for r in realized}
 
 
-@pytest.mark.parametrize("legacy", [False, True], ids=["lean", "legacy"])
-def test_compact_loop_spans_match_stats(tmp_path, legacy):
-    """Both XLA compaction loops: one poll span per scalar pull, one step
-    span per chunk launch, one regather span per full pull."""
+_COMPACT_DRIVERS = {
+    "xla": lambda batch, **kw: engine.simulate_batch_arrays_compact(batch,
+                                                                    **kw),
+    "pallas": lambda batch, **kw: epoch_schedule_compact(
+        batch, tile=8, interpret=True, **kw),
+}
+
+
+@pytest.mark.parametrize("backend", sorted(_COMPACT_DRIVERS))
+def test_compact_loop_spans_match_stats(tmp_path, backend):
+    """The compaction loop on either backend: one poll span per scalar
+    pull, one step span per chunk launch, one regather span per full
+    pull."""
     batch = sweep.grid_arrays(_tail_plan().params(), pad_tasks=25, pad_vms=8)
-    engine.simulate_batch_arrays_compact(batch, k=2, legacy=legacy)
+    run = _COMPACT_DRIVERS[backend]
+    run(batch, k=2)
     st = {}
     with jax.profiler.trace(str(tmp_path)):
-        engine.simulate_batch_arrays_compact(batch, k=2, legacy=legacy,
-                                             stats=st)
+        run(batch, k=2, stats=st)
     names = [s[2] for s in _iotsim_spans(tmp_path)]
     assert names.count("iotsim.compact.prepare") == 1
     assert names.count("iotsim.compact.finish") == 1
@@ -534,6 +543,38 @@ def test_compact_loop_spans_match_stats(tmp_path, legacy):
     assert st["compactions"] > 0
     assert st["d2h_transfers"] >= st["syncs"] + st["scalar_syncs"]
     assert st["lane_epochs_allotted"] > 0
+
+
+# The loop's full host census on the tail plan (k=2), open loop and under
+# control, as the two drivers' separate loops recorded it: any change to
+# the device calls the loop makes shows here as a changed count.
+_CENSUS = {
+    "xla": (dict(syncs=1, scalar_syncs=4, compactions=1, dispatches=3,
+                 lane_epochs_allotted=80, h2d_transfers=6, h2d_bytes=80,
+                 d2h_transfers=5, d2h_bytes=92),
+            dict(syncs=1, scalar_syncs=4, compactions=1, dispatches=3,
+                 lane_epochs_allotted=80, h2d_transfers=6, h2d_bytes=80,
+                 d2h_transfers=11, d2h_bytes=2860)),
+    "pallas": (dict(syncs=1, scalar_syncs=4, compactions=1, dispatches=3,
+                    lane_epochs_allotted=80, h2d_transfers=2, h2d_bytes=64,
+                    d2h_transfers=6, d2h_bytes=84),
+               dict(syncs=1, scalar_syncs=4, compactions=1, dispatches=3,
+                    lane_epochs_allotted=80, h2d_transfers=2, h2d_bytes=64,
+                    d2h_transfers=12, d2h_bytes=2852)),
+}
+
+
+@pytest.mark.parametrize("backend", sorted(_COMPACT_DRIVERS))
+def test_compact_loop_stats_census(backend):
+    """Every counter of the compaction loop, pinned: syncs, launches,
+    allotted lane-epochs and each transfer with its bytes."""
+    batch = sweep.grid_arrays(_tail_plan().params(), pad_tasks=25, pad_vms=8)
+    for control, want in zip((False, True), _CENSUS[backend]):
+        st = {}
+        _, realized = _COMPACT_DRIVERS[backend](batch, k=2, control=control,
+                                                stats=st)
+        assert st == want, (backend, control)
+        assert int(realized) == 5
 
 
 _GLUE_SPANS = ("iotsim.compact.prepare", "iotsim.compact.finish")
